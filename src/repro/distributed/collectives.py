@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 Array = jax.Array
 NEG_INF = -1e30
@@ -70,7 +69,7 @@ def seq_sharded_decode_attention(q: Array, keys: Array, vals: Array,
         out = o_sum / jnp.maximum(l_sum, 1e-30)[..., None]
         return out.reshape(q_l.shape[0], 1, H, hd).astype(q_l.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, None, None), P(bspec, axis, None, None),
                   P(bspec, axis, None, None), P(bspec)),
@@ -120,7 +119,7 @@ def seq_sharded_decode_step(q: Array, cache_k: Array, cache_v: Array,
         out = o_sum / jnp.maximum(l_sum, 1e-30)[..., None]
         return out.reshape(Bl, 1, H, hd).astype(q_l.dtype), ck, cv
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, None, None), P(bspec, axis, None, None),
                   P(bspec, axis, None, None), P(bspec, None, None, None),

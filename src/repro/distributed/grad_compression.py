@@ -18,7 +18,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size, shard_map
 
 Array = jax.Array
 
@@ -34,7 +33,7 @@ def _dequant(q: Array, scale: Array) -> Array:
 
 def compressed_psum(x: Array, axis_name: str) -> Array:
     """int8 reduce-scatter + all-gather emulation of psum over axis_name."""
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     if g == 1:
         return x
     shape = x.shape
@@ -77,11 +76,12 @@ def make_compressed_dp_allreduce(mesh, axes=("pod", "data")):
             out = t
             for a in names:
                 out = jax.tree.map(
-                    lambda x: compressed_psum(x, a) / axis_size(a),
+                    lambda x: compressed_psum(x, a) / jax.lax.axis_size(a),
                     out)
             return out
 
-        return shard_map(body, mesh=mesh,
-                             in_specs=P(*names), out_specs=P(*names))(tree)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=P(*names),
+                             out_specs=P(*names))(tree)
 
     return reducer

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .kv_quant import ERROR_BOUND, QMAX
-from .sgmv import _pick_block
+from .sgmv import _interpret, _pick_block
 
 Array = jax.Array
 
@@ -69,7 +69,7 @@ def _norm_axis(ndim: int, axis: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("axis", "block", "interpret"))
 def adapter_quantize(w: Array, *, axis: int = -1, block: int = 256,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Quantize a bank of weight matrices ``w (..., R, C)`` to int8 plus
     float32 per-output-channel scales (keepdims along ``axis``)."""
     if w.ndim < 2:
@@ -101,7 +101,7 @@ def adapter_quantize(w: Array, *, axis: int = -1, block: int = 256,
         out_specs=[pl.BlockSpec(blk, idx), pl.BlockSpec(s_blk, idx)],
         out_shape=[jax.ShapeDtypeStruct((n, R, C), jnp.int8),
                    jax.ShapeDtypeStruct(s_shape, jnp.float32)],
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(x)
     s_out = lead + ((R, 1) if rows else (1, C))
     return q.reshape(w.shape), s.reshape(s_out)
@@ -111,7 +111,7 @@ def adapter_quantize(w: Array, *, axis: int = -1, block: int = 256,
                                              "interpret"))
 def adapter_dequantize(q: Array, scales: Array, *,
                        out_dtype=jnp.float32, block: int = 256,
-                       interpret: bool = True) -> Array:
+                       interpret: bool | None = None) -> Array:
     """Inverse of `adapter_quantize`; the reduction axis is recovered from
     the keepdims position in ``scales``."""
     rows = scales.shape[-1] == 1
@@ -136,7 +136,7 @@ def adapter_dequantize(q: Array, scales: Array, *,
         in_specs=[pl.BlockSpec(blk, idx), pl.BlockSpec(s_blk, idx)],
         out_specs=pl.BlockSpec(blk, idx),
         out_shape=jax.ShapeDtypeStruct((n, R, C), out_dtype),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(q.reshape(n, R, C), scales.reshape(s_shape))
     return out.reshape(q.shape)
 

@@ -30,10 +30,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .sgmv import _pick_block
+from .sgmv import _interpret, _pick_block
 
 Array = jax.Array
 NEG_INF = -1e30
+
+
+def _flat_kv(x: Array) -> Array:
+    """(B|P, S, Kv, hd) -> (B|P, S, Kv*hd), a free reshape.  Mosaic tiles
+    the two minor dims of a block; with the heads folded into the lane
+    axis, one kv-head's block is (tokens, hd) at lane offset h*hd instead
+    of a 1-wide slice of the Kv axis, which the TPU cannot tile."""
+    return x.reshape(x.shape[0], x.shape[1], -1)
+
+
+def _kv_block(bs: int, hd: int):
+    """Block shape of one (sequence or page, kv-head) K/V tile over
+    :func:`_flat_kv` layout; index maps address it as (b, s, h)."""
+    return (None, bs, hd)
 
 
 def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, l_ref, m_ref,
@@ -50,8 +64,8 @@ def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, l_ref, m_ref,
 
     q = q_ref[0, 0].astype(jnp.float32)                  # (G, hd)
     q = q * (q.shape[-1] ** -0.5)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (bs, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)               # (bs, hd)
+    k = k_ref[...].astype(jnp.float32)                   # (bs, hd)
+    v = v_ref[...].astype(jnp.float32)                   # (bs, hd)
     bs = k.shape[0]
     logits = jax.lax.dot_general(
         q, k, dimension_numbers=(((1,), (1,)), ((), ())),
@@ -78,7 +92,7 @@ def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, l_ref, m_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def flash_decode(q: Array, k: Array, v: Array, kv_len: Array, *,
-                 block_s: int = 512, interpret: bool = True):
+                 block_s: int = 512, interpret: bool | None = None):
     """q: (B, H, hd); k/v: (B, S, Kv, hd); kv_len: (B,) int32.
 
     Returns (out (B, H, hd), l (B, Kv, G, 1), m (B, Kv, G, 1)) — the (l, m)
@@ -97,8 +111,10 @@ def flash_decode(q: Array, k: Array, v: Array, kv_len: Array, *,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G, hd), lambda b, h, s, kl: (b, h, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd), lambda b, h, s, kl: (b, s, h, 0)),
-                pl.BlockSpec((1, bs, 1, hd), lambda b, h, s, kl: (b, s, h, 0)),
+                pl.BlockSpec(_kv_block(bs, hd),
+                             lambda b, h, s, kl: (b, s, h)),
+                pl.BlockSpec(_kv_block(bs, hd),
+                             lambda b, h, s, kl: (b, s, h)),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, G, hd), lambda b, h, s, kl: (b, h, 0, 0)),
@@ -116,8 +132,8 @@ def flash_decode(q: Array, k: Array, v: Array, kv_len: Array, *,
             jax.ShapeDtypeStruct((B, Kv, G, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, Kv, G, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(kv_len, qg, k, v)
+        interpret=_interpret(interpret),
+    )(kv_len, qg, _flat_kv(k), _flat_kv(v))
     return out.reshape(B, H, hd), l, m
 
 
@@ -134,7 +150,7 @@ def _decode_paged_kernel(pt_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def flash_decode_paged(q: Array, k_pages: Array, v_pages: Array,
                        page_table: Array, kv_len: Array, *,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Gathered-page flash decode over a unified paged KV pool.
 
     q: (B, H, hd); k_pages/v_pages: (P, page_t, Kv, hd) — the pool's
@@ -160,10 +176,10 @@ def flash_decode_paged(q: Array, k_pages: Array, v_pages: Array,
             in_specs=[
                 pl.BlockSpec((1, 1, G, hd),
                              lambda b, h, s, pt, kl: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_t, 1, hd),
-                             lambda b, h, s, pt, kl: (pt[b, s], 0, h, 0)),
-                pl.BlockSpec((1, page_t, 1, hd),
-                             lambda b, h, s, pt, kl: (pt[b, s], 0, h, 0)),
+                pl.BlockSpec(_kv_block(page_t, hd),
+                             lambda b, h, s, pt, kl: (pt[b, s], 0, h)),
+                pl.BlockSpec(_kv_block(page_t, hd),
+                             lambda b, h, s, pt, kl: (pt[b, s], 0, h)),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, G, hd),
@@ -184,6 +200,6 @@ def flash_decode_paged(q: Array, k_pages: Array, v_pages: Array,
             jax.ShapeDtypeStruct((B, Kv, G, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, Kv, G, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(page_table, kv_len, qg, k_pages, v_pages)
+        interpret=_interpret(interpret),
+    )(page_table, kv_len, qg, _flat_kv(k_pages), _flat_kv(v_pages))
     return out.reshape(B, H, hd), l, m

@@ -43,8 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_decode import _decode_kernel
-from .sgmv import _pick_block
+from .flash_decode import _decode_kernel, _flat_kv, _kv_block
+from .sgmv import _interpret, _pick_block
 
 Array = jax.Array
 
@@ -127,11 +127,11 @@ def _fused_jd_kernel(ids_ref, cids_ref, kvlen_ref, q_ref, k_ref, v_ref,
     @pl.when((h == nh - 1) & (s == ns - 1))
     def _expand():
         t = t_sc[...]
-        if sig_ref.ndim == 3:                            # JD-Full (1, r, r)
-            t = jnp.dot(t, sig_ref[0].astype(jnp.float32),
-                        preferred_element_type=jnp.float32)
-        else:                                            # JD-Diag (1, r)
-            t = t * sig_ref[...].astype(jnp.float32)
+        sig = sig_ref[...].astype(jnp.float32)           # (1, r) or (r, r)
+        if sig.shape[0] == 1:                            # JD-Diag (r=1 alike)
+            t = t * sig
+        else:                                            # JD-Full
+            t = jnp.dot(t, sig, preferred_element_type=jnp.float32)
         _expand_out(d_ref, t, u_ref, us_ref)
 
 
@@ -152,13 +152,13 @@ def _attn_outs(B, Kv, G, hd, d_out, dtype):
         pl.BlockSpec((1, 1, G, hd), lambda b, h, s, *sc: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, G, 1), lambda b, h, s, *sc: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, G, 1), lambda b, h, s, *sc: (b, h, 0, 0)),
-        pl.BlockSpec((1, d_out), lambda b, h, s, *sc: (b, 0)),
+        pl.BlockSpec((None, 1, d_out), lambda b, h, s, *sc: (b, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((B, Kv, G, hd), dtype),
         jax.ShapeDtypeStruct((B, Kv, G, 1), jnp.float32),
         jax.ShapeDtypeStruct((B, Kv, G, 1), jnp.float32),
-        jax.ShapeDtypeStruct((B, d_out), jnp.float32),
+        jax.ShapeDtypeStruct((B, 1, d_out), jnp.float32),
     ]
     return out_specs, out_shape
 
@@ -179,7 +179,7 @@ def fused_decode_lora(q: Array, k: Array, v: Array, kv_len: Array,
                       ids: Array, A: Array, B: Array,
                       a_scale: Array | None = None,
                       b_scale: Array | None = None, *,
-                      block_s: int = 512, interpret: bool = True):
+                      block_s: int = 512, interpret: bool | None = None):
     """Fused decode attention + raw-LoRA output delta.
 
     q: (B, H, hd); k/v: (B, S, Kv, hd); kv_len/ids: (B,) int32;
@@ -211,10 +211,10 @@ def fused_decode_lora(q: Array, k: Array, v: Array, kv_len: Array,
             in_specs=[
                 pl.BlockSpec((1, 1, G, hd),
                              lambda b, h, s, ids, kl: (b, h, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, s, ids, kl: (b, s, h, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, s, ids, kl: (b, s, h, 0)),
+                pl.BlockSpec(_kv_block(bs, hd),
+                             lambda b, h, s, ids, kl: (b, s, h)),
+                pl.BlockSpec(_kv_block(bs, hd),
+                             lambda b, h, s, ids, kl: (b, s, h)),
                 pl.BlockSpec((1, r, G * hd),
                              lambda b, h, s, ids, kl: (ids[b], 0, h)),
                 pl.BlockSpec((1, r, 1),
@@ -228,10 +228,10 @@ def fused_decode_lora(q: Array, k: Array, v: Array, kv_len: Array,
             scratch_shapes=_scratch(G, hd, r),
         ),
         out_shape=out_shape,
-        interpret=interpret,
-    )(ids, kv_len, qg, k, v, A, a_scale, B, b_scale)
+        interpret=_interpret(interpret),
+    )(ids, kv_len, qg, _flat_kv(k), _flat_kv(v), A, a_scale, B, b_scale)
     del l, m
-    return out.reshape(Bt, H, hd), delta
+    return out.reshape(Bt, H, hd), delta[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -240,7 +240,7 @@ def fused_decode_lora_paged(q: Array, k_pages: Array, v_pages: Array,
                             A: Array, B: Array,
                             a_scale: Array | None = None,
                             b_scale: Array | None = None, *,
-                            interpret: bool = True):
+                            interpret: bool | None = None):
     """Paged-KV variant of :func:`fused_decode_lora` (layout contract of
     `flash_decode_paged`: k/v_pages (P, page_t, Kv, hd) + page_table
     (B, n_blocks))."""
@@ -263,10 +263,10 @@ def fused_decode_lora_paged(q: Array, k_pages: Array, v_pages: Array,
             in_specs=[
                 pl.BlockSpec((1, 1, G, hd),
                              lambda b, h, s, pt, ids, kl: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_t, 1, hd),
-                             lambda b, h, s, pt, ids, kl: (pt[b, s], 0, h, 0)),
-                pl.BlockSpec((1, page_t, 1, hd),
-                             lambda b, h, s, pt, ids, kl: (pt[b, s], 0, h, 0)),
+                pl.BlockSpec(_kv_block(page_t, hd),
+                             lambda b, h, s, pt, ids, kl: (pt[b, s], 0, h)),
+                pl.BlockSpec(_kv_block(page_t, hd),
+                             lambda b, h, s, pt, ids, kl: (pt[b, s], 0, h)),
                 pl.BlockSpec((1, r, G * hd),
                              lambda b, h, s, pt, ids, kl: (ids[b], 0, h)),
                 pl.BlockSpec((1, r, 1),
@@ -280,18 +280,24 @@ def fused_decode_lora_paged(q: Array, k_pages: Array, v_pages: Array,
             scratch_shapes=_scratch(G, hd, r),
         ),
         out_shape=out_shape,
-        interpret=interpret,
-    )(page_table, ids, kv_len, qg, k_pages, v_pages, A, a_scale, B, b_scale)
+        interpret=_interpret(interpret),
+    )(page_table, ids, kv_len, qg, _flat_kv(k_pages),
+      _flat_kv(v_pages), A, a_scale, B, b_scale)
     del l, m
-    return out.reshape(Bt, H, hd), delta
+    return out.reshape(Bt, H, hd), delta[:, 0]
 
 
-def _jd_sigma_spec(sigma, r, pos):
-    """BlockSpec for the per-slot Sigma: (n, r) diag or (n, r, r) full.
-    ``pos`` is the index of `ids` among the scalar-prefetch refs."""
-    if sigma.ndim == 2:
-        return pl.BlockSpec((1, r), lambda b, h, s, *sc: (sc[pos][b], 0))
-    return pl.BlockSpec((1, r, r), lambda b, h, s, *sc: (sc[pos][b], 0, 0))
+def _jd_sigma(sigma):
+    """Per-slot Sigma as (n, 1, r) diag or (n, r, r) full: one adapter's
+    block then spans the two minor dims whole, which Mosaic can tile."""
+    return sigma[:, None, :] if sigma.ndim == 2 else sigma
+
+
+def _jd_sigma_spec(sigma, pos):
+    """BlockSpec for one slot's :func:`_jd_sigma` matrix.  ``pos`` is the
+    index of `ids` among the scalar-prefetch refs."""
+    return pl.BlockSpec((None,) + sigma.shape[1:],
+                        lambda b, h, s, *sc: (sc[pos][b], 0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
@@ -299,7 +305,7 @@ def fused_decode_jd(q: Array, k: Array, v: Array, kv_len: Array, ids: Array,
                     U: Array, V: Array, sigma: Array, cluster_of: Array,
                     u_scale: Array | None = None,
                     v_scale: Array | None = None, *,
-                    block_s: int = 512, interpret: bool = True):
+                    block_s: int = 512, interpret: bool | None = None):
     """Fused decode attention + compressed shared-basis (jd) output delta.
 
     U: (k_clusters, d_out, r) / V: (k_clusters, H*hd, r) fp or int8 with
@@ -316,6 +322,7 @@ def fused_decode_jd(q: Array, k: Array, v: Array, kv_len: Array, ids: Array,
     if d_attn != H * hd:
         raise ValueError(f"V maps {d_attn} dims, attention makes {H * hd}")
     cids = cluster_of[ids].astype(jnp.int32)
+    sigma = _jd_sigma(sigma)
     u_scale = _ones((kcl, d_out, 1)) if u_scale is None else u_scale
     v_scale = _ones((kcl, 1, r)) if v_scale is None else v_scale
     bs = _pick_block(S, block_s)
@@ -330,15 +337,15 @@ def fused_decode_jd(q: Array, k: Array, v: Array, kv_len: Array, ids: Array,
             in_specs=[
                 pl.BlockSpec((1, 1, G, hd),
                              lambda b, h, s, ids, ci, kl: (b, h, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, s, ids, ci, kl: (b, s, h, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, s, ids, ci, kl: (b, s, h, 0)),
+                pl.BlockSpec(_kv_block(bs, hd),
+                             lambda b, h, s, ids, ci, kl: (b, s, h)),
+                pl.BlockSpec(_kv_block(bs, hd),
+                             lambda b, h, s, ids, ci, kl: (b, s, h)),
                 pl.BlockSpec((1, G * hd, r),
                              lambda b, h, s, ids, ci, kl: (ci[b], h, 0)),
                 pl.BlockSpec((1, 1, r),
                              lambda b, h, s, ids, ci, kl: (ci[b], 0, 0)),
-                _jd_sigma_spec(sigma, r, 0),
+                _jd_sigma_spec(sigma, 0),
                 pl.BlockSpec((1, d_out, r),
                              lambda b, h, s, ids, ci, kl: (ci[b], 0, 0)),
                 pl.BlockSpec((1, d_out, 1),
@@ -348,10 +355,11 @@ def fused_decode_jd(q: Array, k: Array, v: Array, kv_len: Array, ids: Array,
             scratch_shapes=_scratch(G, hd, r),
         ),
         out_shape=out_shape,
-        interpret=interpret,
-    )(ids, cids, kv_len, qg, k, v, V, v_scale, sigma, U, u_scale)
+        interpret=_interpret(interpret),
+    )(ids, cids, kv_len, qg, _flat_kv(k), _flat_kv(v), V, v_scale, sigma, U,
+      u_scale)
     del l, m
-    return out.reshape(Bt, H, hd), delta
+    return out.reshape(Bt, H, hd), delta[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -361,7 +369,7 @@ def fused_decode_jd_paged(q: Array, k_pages: Array, v_pages: Array,
                           cluster_of: Array,
                           u_scale: Array | None = None,
                           v_scale: Array | None = None, *,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """Paged-KV variant of :func:`fused_decode_jd`."""
     Bt, H, hd = q.shape
     page_t, Kv = k_pages.shape[1], k_pages.shape[2]
@@ -370,6 +378,7 @@ def fused_decode_jd_paged(q: Array, k_pages: Array, v_pages: Array,
     kcl, _, r = V.shape
     d_out = U.shape[1]
     cids = cluster_of[ids].astype(jnp.int32)
+    sigma = _jd_sigma(sigma)
     u_scale = _ones((kcl, d_out, 1)) if u_scale is None else u_scale
     v_scale = _ones((kcl, 1, r)) if v_scale is None else v_scale
     grid = (Bt, Kv, n_blocks)
@@ -383,17 +392,17 @@ def fused_decode_jd_paged(q: Array, k_pages: Array, v_pages: Array,
             in_specs=[
                 pl.BlockSpec((1, 1, G, hd),
                              lambda b, h, s, pt, ids, ci, kl: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_t, 1, hd),
+                pl.BlockSpec(_kv_block(page_t, hd),
                              lambda b, h, s, pt, ids, ci, kl:
-                             (pt[b, s], 0, h, 0)),
-                pl.BlockSpec((1, page_t, 1, hd),
+                             (pt[b, s], 0, h)),
+                pl.BlockSpec(_kv_block(page_t, hd),
                              lambda b, h, s, pt, ids, ci, kl:
-                             (pt[b, s], 0, h, 0)),
+                             (pt[b, s], 0, h)),
                 pl.BlockSpec((1, G * hd, r),
                              lambda b, h, s, pt, ids, ci, kl: (ci[b], h, 0)),
                 pl.BlockSpec((1, 1, r),
                              lambda b, h, s, pt, ids, ci, kl: (ci[b], 0, 0)),
-                _jd_sigma_spec(sigma, r, 1),
+                _jd_sigma_spec(sigma, 1),
                 pl.BlockSpec((1, d_out, r),
                              lambda b, h, s, pt, ids, ci, kl: (ci[b], 0, 0)),
                 pl.BlockSpec((1, d_out, 1),
@@ -403,8 +412,9 @@ def fused_decode_jd_paged(q: Array, k_pages: Array, v_pages: Array,
             scratch_shapes=_scratch(G, hd, r),
         ),
         out_shape=out_shape,
-        interpret=interpret,
-    )(page_table, ids, cids, kv_len, qg, k_pages, v_pages, V, v_scale,
+        interpret=_interpret(interpret),
+    )(page_table, ids, cids, kv_len, qg, _flat_kv(k_pages),
+      _flat_kv(v_pages), V, v_scale,
       sigma, U, u_scale)
     del l, m
-    return out.reshape(Bt, H, hd), delta
+    return out.reshape(Bt, H, hd), delta[:, 0]

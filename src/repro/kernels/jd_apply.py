@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .sgmv import _pick_block
+from .sgmv import _interpret, _pick_block, sgmv_expand, sigma_bmm
 
 Array = jax.Array
 
@@ -49,7 +49,7 @@ def _shrink_scale_kernel(cids_ref, x_ref, v_ref, sig_ref, o_ref):
                    static_argnames=("block_t", "block_d", "interpret"))
 def jd_shrink_scale(x: Array, V: Array, sigma_tok: Array, tile_cids: Array, *,
                     block_t: int = 128, block_d: int = 512,
-                    interpret: bool = True) -> Array:
+                    interpret: bool | None = None) -> Array:
     """x: (T_pad, d_in); V: (k, d_in, r); sigma_tok: (T_pad, r) pre-gathered
     diag sigmas; tile_cids: (T_pad/block_t,) cluster per tile -> (T_pad, r)."""
     T, d_in = x.shape
@@ -70,14 +70,14 @@ def jd_shrink_scale(x: Array, V: Array, sigma_tok: Array, tile_cids: Array, *,
             out_specs=pl.BlockSpec((bt, r), lambda i, j, ids: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((T, r), jnp.float32),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(tile_cids, x, V, sigma_tok)
 
 
 def jd_apply(x: Array, U: Array, V: Array, sigma: Array, cluster_of: Array,
              ids: Array, tile_cids: Array, tile_ids: Array, *,
              block_t: int = 128, block_d: int = 512,
-             interpret: bool = True) -> Array:
+             interpret: bool | None = None) -> Array:
     """Full compressed delta for grouped tokens.
 
     JD-Diag: fused shrink+scale, then expand with cluster U.
@@ -85,8 +85,6 @@ def jd_apply(x: Array, U: Array, V: Array, sigma: Array, cluster_of: Array,
     Tokens must be grouped so each tile has one adapter (and hence one
     cluster — adapters of a tile share their cluster by construction).
     """
-    from .sgmv import sgmv_expand, sigma_bmm
-
     T = x.shape[0]
     r = V.shape[-1]
     assert T % tile_cids.shape[0] == 0

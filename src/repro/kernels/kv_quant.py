@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .ref import kv_dequant_ref, kv_quant_ref
+from .sgmv import _interpret, _pick_block
 
 Array = jax.Array
 
@@ -46,14 +47,6 @@ WIRE_RATIO = {8: (BLOCK_T + 4) / (2 * BLOCK_T),
               4: (BLOCK_T // 2 + 4) / (2 * BLOCK_T)}
 # worst-case |dequant - x| per channel, as a fraction of the channel absmax
 ERROR_BOUND = {8: 1 / 254, 4: 1 / 14}
-
-
-def _pick_block(dim: int, target: int) -> int:
-    """Largest divisor of `dim` that is <= target (keeps BlockSpecs exact)."""
-    b = min(dim, target)
-    while dim % b:
-        b -= 1
-    return b
 
 
 def _quant_body(x_ref, qmax: float):
@@ -71,15 +64,21 @@ def _quant8_kernel(x_ref, q_ref, s_ref):
 
 
 def _quant4_kernel(x_ref, q_ref, s_ref):
-    q, scale = _quant_body(x_ref, 7.0)
-    qi = q.astype(jnp.int32) & 0xF               # two's-complement nibble
-    q_ref[...] = (qi[0::2] | (qi[1::2] << 4)).astype(jnp.uint8)
+    _, scale = _quant_body(x_ref, 7.0)
+    rows = q_ref.shape[0]
+
+    def nibble(start):                           # even / odd tokens
+        x = x_ref[pl.ds(start, rows, stride=2), :].astype(jnp.float32)
+        q = jnp.clip(jnp.round(x / scale), -7.0, 7.0)
+        return q.astype(jnp.int32) & 0xF         # two's-complement nibble
+
+    q_ref[...] = (nibble(0) | (nibble(1) << 4)).astype(jnp.uint8)
     s_ref[...] = scale
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_c", "interpret"))
 def kv_quantize(x: Array, *, bits: int = 8, block_c: int = 128,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """Quantize a (T, C) KV block per channel.
 
     Returns ``(packed, scales)``: packed is (T, C) int8 for 8 bits or
@@ -104,7 +103,7 @@ def kv_quantize(x: Array, *, bits: int = 8, block_c: int = 128,
                    pl.BlockSpec((1, bc), lambda j: (0, j))],
         out_shape=[jax.ShapeDtypeStruct((rows, C), vdtype),
                    jax.ShapeDtypeStruct((1, C), jnp.float32)],
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(x)
 
 
@@ -127,7 +126,7 @@ def _dequant4_kernel(q_ref, s_ref, o_ref):
                                     "interpret"))
 def kv_dequantize(packed: Array, scales: Array, *, bits: int = 8,
                   out_dtype=jnp.float32, block_c: int = 128,
-                  interpret: bool = True) -> Array:
+                  interpret: bool | None = None) -> Array:
     """Invert :func:`kv_quantize`; returns the (T, C) dequantized block."""
     rows, C = packed.shape
     if bits not in QMAX:
@@ -142,7 +141,7 @@ def kv_dequantize(packed: Array, scales: Array, *, bits: int = 8,
                   pl.BlockSpec((1, bc), lambda j: (0, j))],
         out_specs=pl.BlockSpec((T, bc), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((T, C), out_dtype),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(packed, scales)
 
 

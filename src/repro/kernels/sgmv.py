@@ -27,6 +27,15 @@ def _pick_block(dim: int, target: int) -> int:
     return b
 
 
+def _interpret(interpret: bool | None) -> bool:
+    """Every kernel wrapper's ``interpret`` default (None): the compiled
+    kernel on a TPU backend, the Pallas interpreter anywhere else.  An
+    explicit bool always wins."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
 # -- rank-tile cost model (pure; no jax) -------------------------------------
 #
 # The SGMV kernels contract over the rank axis in hardware tiles: the f32
@@ -80,7 +89,7 @@ def _shrink_kernel(ids_ref, x_ref, a_ref, o_ref):
                    static_argnames=("block_t", "block_d", "interpret"))
 def sgmv_shrink(x: Array, A: Array, tile_ids: Array, *,
                 block_t: int = 128, block_d: int = 512,
-                interpret: bool = True) -> Array:
+                interpret: bool | None = None) -> Array:
     """x: (T_pad, d_in) grouped tokens; A: (n, r, d_in); tile_ids:
     (T_pad/block_t,) adapter id per tile.  Returns (T_pad, r) fp32."""
     T, d_in = x.shape
@@ -101,7 +110,7 @@ def sgmv_shrink(x: Array, A: Array, tile_ids: Array, *,
             out_specs=pl.BlockSpec((bt, r), lambda i, j, ids: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((T, r), jnp.float32),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(tile_ids, x, A)
 
 
@@ -117,7 +126,7 @@ def _expand_kernel(ids_ref, t_ref, b_ref, o_ref):
                    static_argnames=("block_t", "block_d", "interpret"))
 def sgmv_expand(t: Array, B: Array, tile_ids: Array, *,
                 block_t: int = 128, block_d: int = 512,
-                interpret: bool = True) -> Array:
+                interpret: bool | None = None) -> Array:
     """t: (T_pad, r); B: (n, d_out, r); returns (T_pad, d_out) in t.dtype."""
     T, r = t.shape
     n, d_out, _ = B.shape
@@ -137,7 +146,7 @@ def sgmv_expand(t: Array, B: Array, tile_ids: Array, *,
             out_specs=pl.BlockSpec((bt, bd), lambda i, j, ids: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((T, d_out), t.dtype),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(tile_ids, t, B)
 
 
@@ -149,7 +158,7 @@ def _sigma_bmm_kernel(ids_ref, t_ref, s_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
 def sigma_bmm(t: Array, sigma: Array, tile_ids: Array, *,
-              block_t: int = 128, interpret: bool = True) -> Array:
+              block_t: int = 128, interpret: bool | None = None) -> Array:
     """t: (T_pad, r); sigma: (n, r, r); per-tile adapter ids."""
     T, r = t.shape
     bt = _pick_block(T, block_t)
@@ -166,5 +175,5 @@ def sigma_bmm(t: Array, sigma: Array, tile_ids: Array, *,
             out_specs=pl.BlockSpec((bt, r), lambda i, ids: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((T, r), t.dtype),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(tile_ids, t, sigma)

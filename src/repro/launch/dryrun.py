@@ -1,7 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=" + \
     os.environ.get("REPRO_DRYRUN_DEVICES", "512")
-# NOTE: the two lines above MUST run before any jax import (device count is
+os.environ.setdefault("JAX_PLATFORMS", "cpu")   # host devices, never a chip
+# NOTE: the lines above MUST run before any jax import (device count is
 # locked at first backend init).  Everything below is ordinary.
 
 import argparse          # noqa: E402

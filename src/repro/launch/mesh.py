@@ -7,23 +7,13 @@ state.  The dry-run forces 512 host devices via XLA_FLAGS before any import
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axis_names):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist in newer releases; older jax
-    treats every axis as Auto by default, so omitting it is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axis_names)
-    return jax.make_mesh(shape, axis_names,
-                         axis_types=(axis_type.Auto,) * len(axis_names))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for(n_devices: int, model_parallel: int = 1, pods: int = 1):
@@ -32,9 +22,11 @@ def make_mesh_for(n_devices: int, model_parallel: int = 1, pods: int = 1):
     assert n_devices % (model_parallel * pods) == 0, (n_devices, model_parallel, pods)
     data = n_devices // (model_parallel * pods)
     if pods > 1:
-        return make_mesh_compat((pods, data, model_parallel),
-                                ("pod", "data", "model"))
-    return make_mesh_compat((data, model_parallel), ("data", "model"))
+        return jax.make_mesh((pods, data, model_parallel),
+                             ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
+    return jax.make_mesh((data, model_parallel), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_description(mesh) -> dict:

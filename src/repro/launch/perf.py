@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=" + \
     os.environ.get("REPRO_DRYRUN_DEVICES", "512")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")   # host devices, never a chip
 # (must precede any jax import — see dryrun.py)
 
 import argparse          # noqa: E402

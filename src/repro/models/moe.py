@@ -22,7 +22,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain, current_mesh
 from repro.models.param import ParamDef
@@ -176,7 +175,7 @@ def _moe_ep(p: Dict, x: Array, topw: Array, topi: Array, cfg: ModelConfig,
         y = _combine(y_buf, eid, slot, valid, topw_l)
         return jax.lax.psum(y, "model")
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bd[0], None), P(bd[0], None), P(bd[0], None),
                   w_spec, w_spec, w_down_spec),

@@ -55,7 +55,10 @@ def init_params(defs, key: Array, dtype_override=None):
             std = d.scale if d.scale is not None else 1.0 / math.sqrt(fan_in)
             if d.init == "small":
                 std = (d.scale or 1.0) * 0.02
-            out.append((jax.random.normal(k, d.shape, jnp.float32) * std).astype(dt))
+            # wait for each leaf: eager dispatch runs ahead of the device,
+            # and every leaf's float32 intermediates would be live at once
+            out.append(jax.block_until_ready(
+                (jax.random.normal(k, d.shape, jnp.float32) * std).astype(dt)))
     return jax.tree.unflatten(treedef, out)
 
 

@@ -1,5 +1,6 @@
 """Real-model executor: actually runs prefill/decode with batched LoRA
-application on the host (reduced configs).  Wall-clock timed, real logits.
+application on JAX's default device (the TPU on a chip host, the CPU in
+tests).  Wall-clock timed, real logits.
 
 Slot model: a fixed decode batch of ``max_batch`` KV-cache slots; admitted
 requests prefill into a free slot (batch-1 prefill, cache splice); each
@@ -54,10 +55,11 @@ class RealModelExecutor:
                  mode: str, max_batch: int, s_max: int,
                  cluster_of: Optional[np.ndarray] = None,
                  adapter_bytes_override: Optional[int] = None,
-                 decode_path: str = "unfused"):
+                 decode_path: str = "unfused", seed: int = 0):
         """bundles: layer-structured arrays for the adapters:
         mode 'lora': {"layers": {target: {"A": (L,n,r,d), "B": (L,n,d,r)}}}
-        mode 'jd':   {"layers": {target: {"U","V","sigma","cluster_of"}}}"""
+        mode 'jd':   {"layers": {target: {"U","V","sigma","cluster_of"}}}
+        ``seed`` keys the synthetic prompts of :meth:`prompt_for`."""
         if decode_path not in DECODE_PATHS:
             raise ValueError(f"decode_path must be one of {DECODE_PATHS}, "
                              f"got {decode_path!r}")
@@ -67,11 +69,15 @@ class RealModelExecutor:
         self.max_batch = max_batch
         self.s_max = s_max
         self.cluster_of = cluster_of
+        self.seed = seed
         self.cache = tf.init_cache(cfg, max_batch, s_max)
         self.slot_req: List[Optional[int]] = [None] * max_batch
         self.slot_adapter = np.zeros(max_batch, np.int32)
         self.slot_tokens = np.zeros(max_batch, np.int32)
         self.slot_len = np.zeros(max_batch, np.int32)
+        # tokens each request's decode steps emitted: one per engine step,
+        # so a finished request holds exactly max_new_tokens
+        self.outputs: Dict[int, List[int]] = {}
         # host mirror of the cache's scalar index: lets the fused paths pick
         # a static KV bucket without a device sync
         self._host_len = 0
@@ -249,8 +255,10 @@ class RealModelExecutor:
         self._host_len = max(self._host_len, int(req.prompt_len))
         self.slot_req[slot] = req.rid
         self.slot_adapter[slot] = req.adapter_id
-        self.slot_tokens[slot] = int(jnp.argmax(logits[0, -1]))
+        self.slot_tokens[slot] = int(jnp.argmax(
+            logits[0, -1, :self.cfg.vocab_size]))
         self.slot_len[slot] = req.prompt_len
+        self.outputs[req.rid] = []
 
     def decode_step_real(self) -> Dict[int, int]:
         """One decode step for all occupied slots; returns {rid: token}."""
@@ -267,17 +275,26 @@ class RealModelExecutor:
                                               bucket=self._bucket())
         self._host_len += 1
         out = {}
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+        # the unembedding is padded past the vocabulary; those columns are
+        # not tokens
+        nxt = np.asarray(jnp.argmax(logits[:, -1, :self.cfg.vocab_size],
+                                    axis=-1))
         for slot, rid in enumerate(self.slot_req):
             if rid is not None:
                 self.slot_tokens[slot] = nxt[slot]
                 self.slot_len[slot] += 1
                 out[rid] = int(nxt[slot])
+                self.outputs.setdefault(rid, []).append(int(nxt[slot]))
         return out
 
     def release(self, rid: int) -> None:
         slot = self.slot_req.index(rid)
         self.slot_req[slot] = None
+        if all(r is None for r in self.slot_req):
+            # drained: the next wave prefills from position 0 again rather
+            # than decoding after the previous wave's scalar index
+            self.cache["index"] = jnp.zeros((), jnp.int32)
+            self._host_len = 0
 
     # -- live migration (PR 9) ----------------------------------------------
     def export_slot(self, rid: int) -> Dict:
@@ -337,11 +354,17 @@ class RealModelExecutor:
         self.decode_step_real()
         return time.perf_counter() - t0
 
+    def prompt_for(self, req: Request) -> np.ndarray:
+        """The request's synthetic prompt: a function of (seed, rid) only,
+        so a served run and a reference see the same tokens whatever the
+        admission order."""
+        rng = np.random.default_rng((self.seed, req.rid))
+        return rng.integers(0, self.cfg.vocab_size, size=req.prompt_len,
+                            dtype=np.int32)
+
     def prefill_time(self, req: Request) -> float:
         t0 = time.perf_counter()
-        prompt = np.random.randint(0, self.cfg.vocab_size,
-                                   size=req.prompt_len).astype(np.int32)
-        self.prefill_request(req, prompt)
+        self.prefill_request(req, self.prompt_for(req))
         return time.perf_counter() - t0
 
 

@@ -1,0 +1,146 @@
+"""The serving path's Pallas kernels compile for a TPU v5e at qwen3-1.7b
+decode widths (B=8, H=16, Kv=8, hd=128, KV window 256, rank 16,
+d_model 2048, 64 adapters).
+
+Nothing runs: the TPU compiler installed with JAX compiles for a chip that
+is described, not attached, and refuses what Mosaic cannot tile (block
+shapes whose two minor dims are not multiples of (8, 128) or whole) or fit.
+Interpret-mode tests cannot see either.  Each compile must keep the kernel
+as a ``tpu_custom_call``.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.adapter_quant import adapter_quantize
+from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+from repro.kernels.fused_decode import (fused_decode_jd, fused_decode_jd_paged,
+                                        fused_decode_lora,
+                                        fused_decode_lora_paged)
+from repro.kernels.jd_apply import jd_apply
+from repro.kernels.kv_quant import kv_dequantize, kv_quantize
+from repro.kernels.sgmv import sgmv_expand, sgmv_shrink
+
+B, H, KV, HD, S, R, D, N, L = 8, 16, 8, 128, 256, 16, 2048, 64, 28
+PAGES, PAGE_T, T = 32, 128, 256
+I32, BF16, F32, I8 = jnp.int32, jnp.bfloat16, jnp.float32, jnp.int8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _attn(s):
+    return (s((B, H, HD), BF16), s((B, S, KV, HD), BF16),
+            s((B, S, KV, HD), BF16), s((B,), I32))
+
+
+def _paged(s):
+    return (s((B, H, HD), BF16), s((PAGES, PAGE_T, KV, HD), BF16),
+            s((PAGES, PAGE_T, KV, HD), BF16), s((B, S // PAGE_T), I32),
+            s((B,), I32))
+
+
+def _jd_bank(s, sigma_shape):
+    return (s((1, D, R), BF16), s((1, H * HD, R), BF16), s(sigma_shape, BF16),
+            s((N,), I32))
+
+
+# name -> (kernel called with interpret=False, args given the ShapeDtype
+# maker).  The wrappers resolve interpret from the backend, which is the
+# CPU here, so the compiled kernel is asked for explicitly.
+CASES = {
+    "flash_decode": (
+        lambda *a: flash_decode(*a, interpret=False), _attn),
+    "flash_decode_paged": (
+        lambda *a: flash_decode_paged(*a, interpret=False), _paged),
+    "fused_decode_lora": (
+        lambda *a: fused_decode_lora(*a, interpret=False),
+        lambda s: _attn(s) + (s((B,), I32), s((N, R, H * HD), BF16),
+                              s((N, D, R), BF16))),
+    "fused_decode_lora_int8": (
+        lambda *a: fused_decode_lora(*a, interpret=False),
+        lambda s: _attn(s) + (s((B,), I32), s((N, R, H * HD), I8),
+                              s((N, D, R), I8), s((N, R, 1), F32),
+                              s((N, D, 1), F32))),
+    "fused_decode_lora_paged": (
+        lambda *a: fused_decode_lora_paged(*a, interpret=False),
+        lambda s: _paged(s) + (s((B,), I32), s((N, R, H * HD), BF16),
+                               s((N, D, R), BF16))),
+    "fused_decode_jd_full": (
+        lambda *a: fused_decode_jd(*a, interpret=False),
+        lambda s: _attn(s) + (s((B,), I32),) + _jd_bank(s, (N, R, R))),
+    "fused_decode_jd_diag": (
+        lambda *a: fused_decode_jd(*a, interpret=False),
+        lambda s: _attn(s) + (s((B,), I32),) + _jd_bank(s, (N, R))),
+    "fused_decode_jd_paged": (
+        lambda *a: fused_decode_jd_paged(*a, interpret=False),
+        lambda s: _paged(s) + (s((B,), I32),) + _jd_bank(s, (N, R, R))),
+    "sgmv_shrink": (
+        lambda *a: sgmv_shrink(*a, interpret=False),
+        lambda s: (s((T, D), BF16), s((N, R, D), BF16), s((T // 128,), I32))),
+    "sgmv_expand": (
+        lambda *a: sgmv_expand(*a, interpret=False),
+        lambda s: (s((T, R), BF16), s((N, D, R), BF16), s((T // 128,), I32))),
+    "jd_apply_diag": (
+        lambda x, U, V, sg, co, ids, tc, ti: jd_apply(
+            x, U, V, sg, co, ids, tc, ti, interpret=False),
+        lambda s: (s((T, D), BF16), s((1, D, R), BF16), s((1, D, R), BF16),
+                   s((N, R), BF16), s((N,), I32), s((T,), I32),
+                   s((T // 128,), I32), s((T // 128,), I32))),
+    "jd_apply_full": (
+        lambda x, U, V, sg, co, ids, tc, ti: jd_apply(
+            x, U, V, sg, co, ids, tc, ti, interpret=False),
+        lambda s: (s((T, D), BF16), s((1, D, R), BF16), s((1, D, R), BF16),
+                   s((N, R, R), BF16), s((N,), I32), s((T,), I32),
+                   s((T // 128,), I32), s((T // 128,), I32))),
+    "adapter_quantize_rows": (
+        lambda w: adapter_quantize(w, interpret=False),
+        lambda s: (s((L, N, R, D), BF16),)),
+    "adapter_quantize_cols": (
+        lambda w: adapter_quantize(w, axis=-2, interpret=False),
+        lambda s: (s((L, 1, D, R), BF16),)),
+    "kv_quantize_int8": (
+        lambda x: kv_quantize(x, bits=8, interpret=False),
+        lambda s: (s((128, KV * HD), F32),)),
+    "kv_quantize_int4": (
+        lambda x: kv_quantize(x, bits=4, interpret=False),
+        lambda s: (s((128, KV * HD), F32),)),
+    "kv_dequantize_int4": (
+        lambda p, sc: kv_dequantize(p, sc, bits=4, interpret=False),
+        lambda s: (s((64, KV * HD), jnp.uint8), s((1, KV * HD), F32))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    kernel, args = CASES[name]
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(kernel).lower(*args(shape)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -68,15 +68,60 @@ def test_fused_path_matches_unfused_tokens_and_logits(setup):
     l_u, _ = e_u._decode(e_u.params, e_u.bundles, tokens, e_u.cache, ids)
     l_f, _ = e_f._decode(e_f.params, e_f.bundles, tokens, e_f.cache, ids,
                          bucket=e_f._bucket())
-    # one bf16 ulp at logit magnitude; the argmax stream is identical below
-    np.testing.assert_allclose(np.asarray(l_u, np.float32),
-                               np.asarray(l_f, np.float32),
+    # one bf16 ulp at logit magnitude, over the real vocabulary (the
+    # padding columns of the unembedding are not tokens); the argmax
+    # stream is identical below
+    V = cfg.vocab_size
+    np.testing.assert_allclose(np.asarray(l_u[..., :V], np.float32),
+                               np.asarray(l_f[..., :V], np.float32),
                                rtol=0, atol=8e-3)
     e_u2, e_f2 = _executor(setup, "unfused"), _executor(setup, "fused")
     _prefill_all(e_u2, n, prompts)
     _prefill_all(e_f2, n, prompts)
     for _ in range(4):
         assert e_u2.decode_step_real() == e_f2.decode_step_real()
+
+
+@pytest.mark.parametrize("path", DECODE_PATHS)
+def test_emitted_tokens_stay_inside_the_vocabulary(setup, path):
+    """The unembedding is padded to a multiple of 256 columns (vocab 64 ->
+    256 here); no prefill or decode step may emit a padding column."""
+    cfg, params, bundles, n = setup
+    assert cfg.padded_vocab > cfg.vocab_size
+    ex = _executor(setup, path)
+    _prefill_all(ex, n, _prompts())
+    assert (ex.slot_tokens < cfg.vocab_size).all()
+    for _ in range(4):
+        assert all(0 <= t < cfg.vocab_size
+                   for t in ex.decode_step_real().values())
+    assert all(len(toks) == 4 for toks in ex.outputs.values())
+
+
+def test_prompts_are_a_function_of_seed_and_rid(setup):
+    cfg, params, bundles, n = setup
+    req = Request(rid=3, adapter_id=0, prompt_len=12, max_new_tokens=1)
+    a, b = _executor(setup, "unfused"), _executor(setup, "unfused")
+    np.testing.assert_array_equal(a.prompt_for(req), b.prompt_for(req))
+    other = dc.replace(req, rid=4)
+    assert not np.array_equal(a.prompt_for(req), a.prompt_for(other))
+    assert (a.prompt_for(req) < cfg.vocab_size).all()
+
+
+def test_drained_executor_restarts_at_position_zero(setup):
+    """Once every slot is released the scalar cache index goes back to 0,
+    so the next wave decodes right after its own prompts."""
+    cfg, params, bundles, n = setup
+    prompts = _prompts(2)
+    ex = _executor(setup, "fused")
+    _prefill_all(ex, n, prompts)
+    ex.decode_step_real()
+    for rid in prompts:
+        ex.release(rid)
+    assert ex._host_len == 0 and int(ex.cache["index"]) == 0
+    fresh = _executor(setup, "fused")
+    _prefill_all(ex, n, prompts)
+    _prefill_all(fresh, n, prompts)
+    assert ex.decode_step_real() == fresh.decode_step_real()
 
 
 def test_fused_q8_shrinks_residency_and_stays_close(setup):

@@ -9,8 +9,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.distributed.grad_compression import compressed_psum
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("data",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 4, 37))
 
 def body(xs):
@@ -18,8 +18,7 @@ def body(xs):
     comp = compressed_psum(xs, "data")
     return exact, comp
 
-from repro.compat import shard_map
-exact, comp = jax.jit(shard_map(body, mesh=mesh,
+exact, comp = jax.jit(jax.shard_map(body, mesh=mesh,
                                     in_specs=P("data"),
                                     out_specs=P("data")))(x)
 rel = float(jnp.max(jnp.abs(exact - comp)) / jnp.max(jnp.abs(exact)))

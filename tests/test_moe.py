@@ -84,8 +84,9 @@ defs = moe_defs(cfg)
 p = init_params(defs, jax.random.PRNGKey(0), dtype_override=jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.d_model))
 y_dense, aux_d = moe_fwd(p, x, cfg)              # no mesh -> dense oracle
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 with use_mesh(mesh):
     y_ep, aux_e = jax.jit(lambda p, x: moe_fwd(p, x, cfg))(p, x)
 err = float(jnp.max(jnp.abs(y_ep - y_dense)))

@@ -15,8 +15,9 @@ from repro.configs import get_config
 from repro.models import transformer as tf
 from repro.models.param import param_specs
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 
 # divisible -> sharded
 assert spec_for((16, 64), ("batch", "d_ff"), mesh) == P("data", "model")
