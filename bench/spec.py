@@ -1,0 +1,105 @@
+"""What a cell is, read from data: ``BENCHMARK.json`` names each cell's
+configuration and traffic mix, and everything else is found by name.
+
+* configuration ``<c>``: ``bench/configs/<c>.json`` (the published
+  ``config.json`` keys as run, plus ``program`` and ``reference``);
+* traffic mix ``<t>``: ``bench/traffic/<t>.json``;
+* the limits that decide ``correct`` for cell ``<w>``: ``bench/limits/<w>.json``;
+* per-layer metric ``<m>``: ``bench/metrics/<m>.py``, whose ``read(rec)``
+  returns a number or None;
+* plain reference ``<r>``: ``bench/references/<r>.py``.
+
+A later change adds a cell, a mix or a metric as new files and entries,
+without editing any file that is already here.
+"""
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+from pathlib import Path
+from types import ModuleType
+from typing import Dict
+
+BENCH_DIR = Path(__file__).resolve().parent
+ROOT = BENCH_DIR.parent
+
+
+def _load_json(path: Path) -> Dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def _load_module(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@dataclasses.dataclass
+class Cell:
+    name: str
+    config: Dict
+    traffic: Dict
+    limits: Dict
+    chips: int
+    end_to_end: list
+    per_layer: list
+
+
+def benchmark() -> Dict:
+    return _load_json(ROOT / "BENCHMARK.json")
+
+
+def cell(name: str, bench: Dict | None = None) -> Cell:
+    bench = bench or benchmark()
+    found = [w for w in bench["workloads"] if w["name"] == name]
+    if not found:
+        names = ", ".join(w["name"] for w in bench["workloads"])
+        raise KeyError(f"no workload {name!r} in BENCHMARK.json ({names})")
+    w = found[0]
+
+    def applies(m):
+        return "workloads" not in m or name in m["workloads"]
+
+    return Cell(
+        name=name,
+        config=_load_json(BENCH_DIR / "configs" / f"{w['config']}.json"),
+        traffic=_load_json(BENCH_DIR / "traffic" / f"{w['traffic']}.json"),
+        limits=_load_json(BENCH_DIR / "limits" / f"{name}.json"),
+        chips=int(w["chips"]),
+        end_to_end=[m for m in bench["end_to_end"] if applies(m)],
+        per_layer=[m for m in bench["per_layer"] if applies(m)])
+
+
+def metric_reader(name: str) -> ModuleType:
+    return _load_module(BENCH_DIR / "metrics" / f"{name}.py",
+                        "bench_metric_" + name.replace(".", "_"))
+
+
+def reference(name: str) -> ModuleType:
+    return _load_module(BENCH_DIR / "references" / f"{name}.py",
+                        "bench_reference_" + name)
+
+
+def model_config(conf: Dict, traffic: Dict):
+    """The program's `ModelConfig` for a configuration file, keyed by the
+    published ``config.json`` names."""
+    from repro.configs.base import LoRAConfig, ModelConfig
+
+    prog = conf["program"]
+    ad = traffic["adapters"]
+    return ModelConfig(
+        name=conf["name"], family=prog["family"],
+        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
+        num_heads=conf["num_attention_heads"],
+        num_kv_heads=conf["num_key_value_heads"],
+        head_dim=conf.get("head_dim")
+        or conf["hidden_size"] // conf["num_attention_heads"],
+        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
+        qk_norm=prog["qk_norm"], rope_theta=float(conf["rope_theta"]),
+        norm_eps=float(conf["rms_norm_eps"]),
+        tie_embeddings=bool(conf["tie_word_embeddings"]),
+        sliding_window=conf.get("sliding_window") or 0,
+        lora=LoRAConfig(rank=ad["rank"], targets=tuple(ad["targets"])))
