@@ -83,14 +83,16 @@ def attention_defs(cfg: ModelConfig, kv_heads: Optional[int] = None) -> Dict:
     return defs
 
 
+@jax.named_scope("qkv")
 def _qkv(p: Dict, x: Array, cfg: ModelConfig, lora_ctx) -> Tuple[Array, Array, Array]:
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     if lora_ctx is not None:
-        q = lora_mod.apply(lora_ctx, "q", x, q)
-        k = lora_mod.apply(lora_ctx, "k", x, k)
-        v = lora_mod.apply(lora_ctx, "v", x, v)
+        with jax.named_scope("adapter_qkv"):
+            q = lora_mod.apply(lora_ctx, "q", x, q)
+            k = lora_mod.apply(lora_ctx, "k", x, k)
+            v = lora_mod.apply(lora_ctx, "v", x, v)
     if cfg.qkv_bias:
         q = q + p["bq"].astype(q.dtype)
         k = k + p["bk"].astype(k.dtype)

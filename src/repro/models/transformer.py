@@ -173,11 +173,14 @@ def init_cache(cfg: ModelConfig, batch: int, s_max: int, *,
 
 
 def _dense_block(p, x, cfg, *, positions, mode, kv, lora_ctx, causal=True):
-    h, new_kv = attention_fwd(p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps),
-                              cfg, positions=positions, mode=mode, cache=kv,
-                              lora_ctx=lora_ctx, causal=causal)
-    x = x + h
-    x = x + mlp_fwd(p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps))
+    with jax.named_scope("attention"):
+        h, new_kv = attention_fwd(p["attn"],
+                                  rms_norm(x, p["ln1"], cfg.norm_eps), cfg,
+                                  positions=positions, mode=mode, cache=kv,
+                                  lora_ctx=lora_ctx, causal=causal)
+        x = x + h
+    with jax.named_scope("mlp"):
+        x = x + mlp_fwd(p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps))
     return x, new_kv
 
 
@@ -541,7 +544,8 @@ def prefill(params: Dict, batch: Dict, cfg: ModelConfig, cache: Dict,
                               frames=batch.get("frames"), mode="prefill",
                               cache=cache, lora_params=lora_params,
                               lora_ctx_proto=lora_ctx_proto)
-    logits = logits_fwd(params["embed"], h[:, -1:], cfg)
+    with jax.named_scope("logits"):
+        logits = logits_fwd(params["embed"], h[:, -1:], cfg)
     return logits, new_cache
 
 
@@ -550,5 +554,6 @@ def decode_step(params: Dict, tokens: Array, cfg: ModelConfig, cache: Dict,
     h, new_cache, _ = forward(params, cfg, tokens=tokens, mode="decode",
                               cache=cache, lora_params=lora_params,
                               lora_ctx_proto=lora_ctx_proto)
-    logits = logits_fwd(params["embed"], h, cfg)
+    with jax.named_scope("logits"):
+        logits = logits_fwd(params["embed"], h, cfg)
     return logits, new_cache

@@ -19,6 +19,7 @@ import dataclasses
 from typing import Dict, List, Optional, Sequence
 
 
+from . import telemetry
 from .adapter_cache import AdapterCache, CacheConfig
 from .request import Request, ServeStats, weight_key
 from .resources import (PAGE_TOKENS, PagedPool, PagedPoolConfig,
@@ -393,6 +394,8 @@ class ServingEngine:
             self._admitting = None
 
     def submit(self, reqs: Sequence[Request]) -> None:
+        for r in reqs:
+            telemetry.event("engine.submit", rid=r.rid)
         self.waiting.extend(reqs)
         self.waiting.sort(key=lambda r: r.ready_time)
 
@@ -413,6 +416,10 @@ class ServingEngine:
     def _admit(self) -> None:
         admitted = self.scheduler.admit(self.running, self.waiting,
                                         self.cache.resident_ids, self.clock)
+        with telemetry.span("engine.admit", admitted=len(admitted)):
+            self._admit_all(admitted)
+
+    def _admit_all(self, admitted: Sequence[Request]) -> None:
         pending_adapter_pages = 0
         self._page_blocked = False
         for r in admitted:
@@ -575,6 +582,10 @@ class ServingEngine:
 
     def step(self) -> bool:
         """One engine iteration; returns False when fully drained."""
+        with telemetry.span("engine.step", running=len(self.running)):
+            return self._step()
+
+    def _step(self) -> bool:
         if not self.running and not self.waiting:
             return False
         if not self.running and self.waiting:
@@ -606,7 +617,8 @@ class ServingEngine:
         self.stats.peak_batch = max(self.stats.peak_batch, len(self.running))
         self.stats.peak_resident_adapters = max(
             self.stats.peak_resident_adapters, len(self.cache.resident_ids))
-        t_step = self.executor.decode_step_time(self.running)
+        with telemetry.span("engine.decode"):
+            t_step = self.executor.decode_step_time(self.running)
         self.clock += stall + t_step
         self.stats.swap_time += stall
         self.stats.compute_time += t_step

@@ -43,6 +43,7 @@ from repro.kernels.adapter_quant import adapter_quantize
 from repro.models import layers
 from repro.models import transformer as tf
 from repro.models.lora import LoRAContext
+from repro.serving import telemetry
 from repro.serving.request import Request
 
 Array = jax.Array
@@ -180,22 +181,25 @@ class RealModelExecutor:
                    if lora_l is not None else None)
             xin = layers.rms_norm(x, p_l["ln1"], cfg.norm_eps)
             qh, kh, vh = layers._qkv(p_l["attn"], xin, cfg, ctx)
-            qh = layers.apply_rope(qh, cos, sin)
-            kh = layers.apply_rope(kh, cos, sin)
-            ck = jax.lax.dynamic_update_slice(
-                ck, kh.astype(ck.dtype)[None], (li, 0, idx, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cv, vh.astype(cv.dtype)[None], (li, 0, idx, 0, 0))
-            attn, delta = self._fused_attn(qh[:, 0], ck[li, :, :bucket],
-                                           cv[li, :, :bucket], kv_len,
-                                           ids, o_bank, li)
-            y = jnp.einsum("bhk,hkd->bd", attn, p_l["attn"]["wo"])
-            if delta is not None:
-                y = y + (proto.scaling * delta).astype(y.dtype)
-            x = x + y[:, None]
-            x = x + layers.mlp_fwd(
-                p_l["mlp"], layers.rms_norm(x, p_l["ln2"], cfg.norm_eps))
-        logits = layers.logits_fwd(params["embed"], x, cfg)
+            with jax.named_scope("attention"):
+                qh = layers.apply_rope(qh, cos, sin)
+                kh = layers.apply_rope(kh, cos, sin)
+                ck = jax.lax.dynamic_update_slice(
+                    ck, kh.astype(ck.dtype)[None], (li, 0, idx, 0, 0))
+                cv = jax.lax.dynamic_update_slice(
+                    cv, vh.astype(cv.dtype)[None], (li, 0, idx, 0, 0))
+                attn, delta = self._fused_attn(qh[:, 0], ck[li, :, :bucket],
+                                               cv[li, :, :bucket], kv_len,
+                                               ids, o_bank, li)
+                y = jnp.einsum("bhk,hkd->bd", attn, p_l["attn"]["wo"])
+                if delta is not None:
+                    y = y + (proto.scaling * delta).astype(y.dtype)
+                x = x + y[:, None]
+            with jax.named_scope("mlp"):
+                x = x + layers.mlp_fwd(
+                    p_l["mlp"], layers.rms_norm(x, p_l["ln2"], cfg.norm_eps))
+        with jax.named_scope("logits"):
+            logits = layers.logits_fwd(params["embed"], x, cfg)
         new_cache = dict(cache)
         new_cache.update(k=ck, v=cv, index=idx + S)
         return logits, new_cache
@@ -234,10 +238,19 @@ class RealModelExecutor:
 
     def prefill_request(self, req: Request, prompt: np.ndarray) -> None:
         slot = self.slot_req.index(None)
-        c1 = tf.init_cache(self.cfg, 1, self.s_max)
-        logits, c1 = self._prefill(
-            self.params, self.bundles, jnp.asarray(prompt[None]), c1,
-            jnp.asarray([req.adapter_id], jnp.int32))
+        with telemetry.span("executor.prefill", rid=req.rid,
+                            prompt_len=int(req.prompt_len), slot=slot):
+            self._prefill_into(slot, req, prompt)
+
+    def _prefill_into(self, slot: int, req: Request,
+                      prompt: np.ndarray) -> None:
+        span = telemetry.span
+        with span("executor.prefill.cache"):
+            c1 = tf.init_cache(self.cfg, 1, self.s_max)
+        with span("executor.prefill.run"):
+            logits, c1 = self._prefill(
+                self.params, self.bundles, jnp.asarray(prompt[None]), c1,
+                jnp.asarray([req.adapter_id], jnp.int32))
         # splice the single-request cache into the slot batch
         def splice(dst, src):
             if dst.ndim == 0:
@@ -246,45 +259,69 @@ class RealModelExecutor:
             idx = [slice(None)] * dst.ndim
             idx[bdim] = slice(slot, slot + 1)
             return dst.at[tuple(idx)].set(src)
-        self.cache = jax.tree.map(splice, self.cache, c1)
-        # advance the shared scalar index to the deepest prefilled slot so
-        # decode continues AFTER the prompt instead of overwriting it (the
-        # splice alone keeps dst's scalar leaves, i.e. a stale index)
-        self.cache["index"] = jnp.maximum(
-            self.cache["index"], jnp.asarray(req.prompt_len, jnp.int32))
+        with span("executor.prefill.splice"):
+            self.cache = jax.tree.map(splice, self.cache, c1)
+            # advance the shared scalar index to the deepest prefilled slot
+            # so decode continues AFTER the prompt instead of overwriting it
+            # (the splice alone keeps dst's scalar leaves, a stale index)
+            self.cache["index"] = jnp.maximum(
+                self.cache["index"], jnp.asarray(req.prompt_len, jnp.int32))
         self._host_len = max(self._host_len, int(req.prompt_len))
         self.slot_req[slot] = req.rid
         self.slot_adapter[slot] = req.adapter_id
-        self.slot_tokens[slot] = int(jnp.argmax(
-            logits[0, -1, :self.cfg.vocab_size]))
+        with span("executor.prefill.sample"):
+            first = jnp.argmax(logits[0, -1, :self.cfg.vocab_size])
+        # the host read in two calls: waiting for the device, then the copy
+        with span("executor.prefill.wait"):
+            first.block_until_ready()
+        with span("executor.prefill.fetch"):
+            self.slot_tokens[slot] = int(first)
         self.slot_len[slot] = req.prompt_len
         self.outputs[req.rid] = []
 
     def decode_step_real(self) -> Dict[int, int]:
         """One decode step for all occupied slots; returns {rid: token}."""
-        tokens = jnp.asarray(self.slot_tokens[:, None])
-        ids = jnp.asarray(self.slot_adapter)
+        unfused = self.decode_path == "unfused"
+        # the attended KV window: each new one is a new fused-step program
+        bucket = self.s_max if unfused else self._bucket()
+        with telemetry.span("executor.decode", slots=self.max_batch,
+                            batch=self.max_batch - self.slot_req.count(None),
+                            bucket=bucket):
+            return self._decode_step(unfused, bucket)
+
+    def _decode_step(self, unfused: bool, bucket: int) -> Dict[int, int]:
+        span = telemetry.span
+        with span("executor.decode.inputs"):
+            tokens = jnp.asarray(self.slot_tokens[:, None])
+            ids = jnp.asarray(self.slot_adapter)
         # index must be per-slot; our cache uses a scalar index — decode at
         # max occupied length (padding slots attend junk but are ignored)
-        if self.decode_path == "unfused":
-            logits, self.cache = self._decode(self.params, self.bundles,
-                                              tokens, self.cache, ids)
-        else:
-            logits, self.cache = self._decode(self.params, self.bundles,
-                                              tokens, self.cache, ids,
-                                              bucket=self._bucket())
+        with span("executor.decode.launch"):
+            if unfused:
+                logits, self.cache = self._decode(self.params, self.bundles,
+                                                  tokens, self.cache, ids)
+            else:
+                logits, self.cache = self._decode(self.params, self.bundles,
+                                                  tokens, self.cache, ids,
+                                                  bucket=bucket)
         self._host_len += 1
         out = {}
         # the unembedding is padded past the vocabulary; those columns are
         # not tokens
-        nxt = np.asarray(jnp.argmax(logits[:, -1, :self.cfg.vocab_size],
-                                    axis=-1))
-        for slot, rid in enumerate(self.slot_req):
-            if rid is not None:
-                self.slot_tokens[slot] = nxt[slot]
-                self.slot_len[slot] += 1
-                out[rid] = int(nxt[slot])
-                self.outputs.setdefault(rid, []).append(int(nxt[slot]))
+        with span("executor.decode.sample"):
+            nxt = jnp.argmax(logits[:, -1, :self.cfg.vocab_size], axis=-1)
+        # the host read in two calls: waiting for the device, then the copy
+        with span("executor.decode.wait"):
+            nxt.block_until_ready()
+        with span("executor.decode.fetch"):
+            nxt = np.asarray(nxt)
+        with span("executor.decode.emit"):
+            for slot, rid in enumerate(self.slot_req):
+                if rid is not None:
+                    self.slot_tokens[slot] = nxt[slot]
+                    self.slot_len[slot] += 1
+                    out[rid] = int(nxt[slot])
+                    self.outputs.setdefault(rid, []).append(int(nxt[slot]))
         return out
 
     def release(self, rid: int) -> None:
