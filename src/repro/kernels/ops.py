@@ -71,41 +71,49 @@ def jd_apply(x: Array, U: Array, V: Array, sigma: Array, cluster_of: Array,
 
 
 def decode_attention(q: Array, k: Array, v: Array, kv_len: Array, *,
-                     use_pallas="auto") -> Array:
-    """Decode attention (one token per sequence)."""
+                     layer=None, window=None, use_pallas="auto") -> Array:
+    """Decode attention (one token per sequence).  k/v: (B, S, Kv, hd), or
+    the stacked (L, B, S, Kv, hd) cache read at ``layer``; a static
+    ``window`` attends the first ``window`` tokens."""
     impl = resolve_impl(use_pallas)
     if impl == "ref":
-        return ref_mod.flash_decode_ref(q, k, v, kv_len)
-    out, _, _ = _flash_decode_pallas(q, k, v, kv_len,
+        return ref_mod.flash_decode_ref(q, k, v, kv_len, layer, window)
+    out, _, _ = _flash_decode_pallas(q, k, v, kv_len, layer=layer,
+                                     window=window,
                                      interpret=(impl == "interpret"))
     return out
 
 
 def fused_lora_decode(q: Array, k: Array, v: Array, kv_len: Array,
                       ids: Array, A: Array, B: Array,
-                      a_scale=None, b_scale=None, *, use_pallas="auto"):
+                      a_scale=None, b_scale=None, *, layer=None, window=None,
+                      use_pallas="auto"):
     """Fused decode attention + per-slot raw-LoRA output delta
     (`fused_decode.fused_decode_lora`): attention and the adapter shrink/
-    expand in ONE kernel pass.  Optional per-channel scales serve int8
+    expand in ONE kernel pass.  k/v, ``layer`` and ``window`` as
+    :func:`decode_attention`.  Optional per-channel scales serve int8
     banks from `adapter_quant.py`.  Returns (out (B,H,hd), delta (B,d_out))."""
     impl = resolve_impl(use_pallas)
     if impl == "ref":
         return ref_mod.fused_decode_lora_ref(q, k, v, kv_len, ids, A, B,
-                                             a_scale, b_scale)
+                                             a_scale, b_scale, layer, window)
     return _fused_lora_pallas(q, k, v, kv_len, ids, A, B, a_scale, b_scale,
+                              layer=layer, window=window,
                               interpret=(impl == "interpret"))
 
 
 def fused_jd_decode(q: Array, k: Array, v: Array, kv_len: Array, ids: Array,
                     U: Array, V: Array, sigma: Array, cluster_of: Array,
-                    u_scale=None, v_scale=None, *, use_pallas="auto"):
+                    u_scale=None, v_scale=None, *, layer=None, window=None,
+                    use_pallas="auto"):
     """Fused decode attention + compressed shared-basis output delta
-    (`fused_decode.fused_decode_jd`)."""
+    (`fused_decode.fused_decode_jd`); k/v, ``layer`` and ``window`` as
+    :func:`decode_attention`."""
     impl = resolve_impl(use_pallas)
     if impl == "ref":
         return ref_mod.fused_decode_jd_ref(q, k, v, kv_len, ids, U, V,
                                            sigma, cluster_of, u_scale,
-                                           v_scale)
+                                           v_scale, layer, window)
     return _fused_jd_pallas(q, k, v, kv_len, ids, U, V, sigma, cluster_of,
-                            u_scale, v_scale,
+                            u_scale, v_scale, layer=layer, window=window,
                             interpret=(impl == "interpret"))

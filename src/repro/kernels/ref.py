@@ -78,9 +78,23 @@ def kv_dequant_ref(q: Array, scale: Array,
     return (q.astype(jnp.float32) * scale.astype(jnp.float32)).astype(out_dtype)
 
 
+def _layer_window(x: Array, layer: Optional[int] = None,
+                 window: Optional[int] = None) -> Array:
+    """The (B, window, Kv, hd) K or V a decode kernel attends: layer
+    ``layer`` of a stacked (L, B, S, Kv, hd) cache (or ``x`` itself, a
+    (B, S, Kv, hd) operand), cut to its first ``window`` tokens."""
+    if layer is not None:
+        x = x[layer]
+    return x if window is None else x[:, :window]
+
+
 def flash_decode_ref(q: Array, k: Array, v: Array,
-                     kv_len: Optional[Array] = None) -> Array:
-    """Decode attention oracle.  q: (B, H, hd); k/v: (B, S, Kv, hd)."""
+                     kv_len: Optional[Array] = None,
+                     layer: Optional[int] = None,
+                     window: Optional[int] = None) -> Array:
+    """Decode attention oracle.  q: (B, H, hd); k/v: (B, S, Kv, hd), or
+    the stacked cache read at ``layer`` (:func:`_layer_window`)."""
+    k, v = _layer_window(k, layer, window), _layer_window(v, layer, window)
     B, H, hd = q.shape
     S, Kv = k.shape[1], k.shape[2]
     G = H // Kv
@@ -169,13 +183,20 @@ def _deq(w: Array, scale: Optional[Array]) -> Array:
 
 
 def fused_decode_lora_ref(q: Array, k: Array, v: Array, kv_len, ids: Array,
-                          A: Array, B: Array, a_scale=None, b_scale=None
+                          A: Array, B: Array, a_scale=None, b_scale=None,
+                          layer: Optional[int] = None,
+                          window: Optional[int] = None
                           ) -> Tuple[Array, Array]:
     """Composed oracle for `fused_decode.fused_decode_lora`: decode
     attention, then the per-slot LoRA delta on the flattened (H*hd)
     attention output.  Optional per-channel scales dequantize int8 banks
-    (`adapter_quant_ref`); returns (out (B,H,hd), delta (B,d_out) f32)."""
-    out = flash_decode_ref(q, k, v, kv_len)
+    (`adapter_quant_ref`); banks with a leading layer axis are read at
+    ``layer``.  Returns (out (B,H,hd), delta (B,d_out) f32)."""
+    out = flash_decode_ref(q, k, v, kv_len, layer, window)
+    if A.ndim == 4:                          # layer-stacked banks
+        A, B = A[layer], B[layer]
+        a_scale, b_scale = (None if x is None else x[layer]
+                            for x in (a_scale, b_scale))
     of = out.reshape(out.shape[0], -1).astype(jnp.float32)
     t = jnp.einsum("bd,brd->br", of, _deq(A, a_scale)[ids])
     delta = jnp.einsum("br,bor->bo", t, _deq(B, b_scale)[ids])
@@ -184,11 +205,19 @@ def fused_decode_lora_ref(q: Array, k: Array, v: Array, kv_len, ids: Array,
 
 def fused_decode_jd_ref(q: Array, k: Array, v: Array, kv_len, ids: Array,
                         U: Array, V: Array, sigma: Array, cluster_of: Array,
-                        u_scale=None, v_scale=None) -> Tuple[Array, Array]:
+                        u_scale=None, v_scale=None,
+                        layer: Optional[int] = None,
+                        window: Optional[int] = None) -> Tuple[Array, Array]:
     """Composed oracle for `fused_decode.fused_decode_jd`: attention, then
     the compressed shared-basis delta (V^T -> Sigma -> U) with per-slot
-    sigma and per-cluster bases."""
-    out = flash_decode_ref(q, k, v, kv_len)
+    sigma and per-cluster bases; layer-stacked banks (4-D U) are read at
+    ``layer``."""
+    out = flash_decode_ref(q, k, v, kv_len, layer, window)
+    if U.ndim == 4:                          # layer-stacked banks
+        U, V, sigma, cluster_of = (x[layer] for x in (U, V, sigma,
+                                                      cluster_of))
+        u_scale, v_scale = (None if x is None else x[layer]
+                            for x in (u_scale, v_scale))
     of = out.reshape(out.shape[0], -1).astype(jnp.float32)
     cid = cluster_of[ids]
     t = jnp.einsum("bd,bdr->br", of, _deq(V, v_scale)[cid])

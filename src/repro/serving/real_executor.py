@@ -162,6 +162,10 @@ class RealModelExecutor:
         else:
             qkv_banks = {t: tp for t, tp in banks.items() if t != "o"}
         o_bank = banks.get("o")
+        if o_bank is not None:
+            o_args, o_kw = self._o_bank_args(o_bank)
+            fused = (kops.fused_lora_decode if self.mode == "lora"
+                     else kops.fused_jd_decode)
         proto = self._ctx(ids)
 
         x = layers.embed_tokens(params["embed"], tokens)
@@ -188,9 +192,17 @@ class RealModelExecutor:
                     ck, kh.astype(ck.dtype)[None], (li, 0, idx, 0, 0))
                 cv = jax.lax.dynamic_update_slice(
                     cv, vh.astype(cv.dtype)[None], (li, 0, idx, 0, 0))
-                attn, delta = self._fused_attn(qh[:, 0], ck[li, :, :bucket],
-                                               cv[li, :, :bucket], kv_len,
-                                               ids, o_bank, li)
+                # the kernel reads layer li's first `bucket` tokens (and
+                # its o-bank rows) in place: the donated cache is never
+                # sliced or copied
+                at = dict(layer=li, window=bucket)
+                if o_bank is None:
+                    attn = kops.decode_attention(qh[:, 0], ck, cv, kv_len,
+                                                 **at)
+                    delta = None
+                else:
+                    attn, delta = fused(qh[:, 0], ck, cv, kv_len, ids,
+                                        *o_args, **o_kw, **at)
                 y = jnp.einsum("bhk,hkd->bd", attn, p_l["attn"]["wo"])
                 if delta is not None:
                     y = y + (proto.scaling * delta).astype(y.dtype)
@@ -204,30 +216,24 @@ class RealModelExecutor:
         new_cache.update(k=ck, v=cv, index=idx + S)
         return logits, new_cache
 
-    def _fused_attn(self, q1, k_l, v_l, kv_len, ids, o_bank, li):
-        """One layer's decode attention (+ fused o-delta when the bundles
-        carry an "o" target)."""
-        if o_bank is None:
-            return kops.decode_attention(q1, k_l, v_l, kv_len), None
+    def _o_bank_args(self, o_bank):
+        """The o-target's layer-stacked banks as the fused kernel takes
+        them: positional banks and scale keywords.  The kernel reads each
+        layer's rows in place; only a packed full Sigma is unpacked here,
+        once per step."""
+        if self.decode_path != "fused_q8":
+            if self.mode == "lora":
+                return (o_bank["A"], o_bank["B"]), {}
+            return (o_bank["U"], o_bank["V"], o_bank["sigma"],
+                    o_bank["cluster_of"]), {}
         if self.mode == "lora":
-            if self.decode_path == "fused_q8":
-                return kops.fused_lora_decode(
-                    q1, k_l, v_l, kv_len, ids,
-                    o_bank["A_q"][li], o_bank["B_q"][li],
-                    a_scale=o_bank["A_s"][li], b_scale=o_bank["B_s"][li])
-            return kops.fused_lora_decode(q1, k_l, v_l, kv_len, ids,
-                                          o_bank["A"][li], o_bank["B"][li])
-        if self.decode_path == "fused_q8":
-            sigma = (o_bank["sigma"][li] if "sigma" in o_bank else
-                     kref.adapter_dequant_ref(o_bank["sigma_q"][li],
-                                              o_bank["sigma_s"][li]))
-            return kops.fused_jd_decode(
-                q1, k_l, v_l, kv_len, ids, o_bank["U_q"][li],
-                o_bank["V_q"][li], sigma, o_bank["cluster_of"][li],
-                u_scale=o_bank["U_s"][li], v_scale=o_bank["V_s"][li])
-        return kops.fused_jd_decode(
-            q1, k_l, v_l, kv_len, ids, o_bank["U"][li], o_bank["V"][li],
-            o_bank["sigma"][li], o_bank["cluster_of"][li])
+            return ((o_bank["A_q"], o_bank["B_q"]),
+                    dict(a_scale=o_bank["A_s"], b_scale=o_bank["B_s"]))
+        sigma = (o_bank["sigma"] if "sigma" in o_bank else
+                 kref.adapter_dequant_ref(o_bank["sigma_q"],
+                                          o_bank["sigma_s"]))
+        return ((o_bank["U_q"], o_bank["V_q"], sigma, o_bank["cluster_of"]),
+                dict(u_scale=o_bank["U_s"], v_scale=o_bank["V_s"]))
 
     # -- engine interface ---------------------------------------------------
     def adapter_bytes(self, aid: int) -> int:

@@ -135,6 +135,45 @@ CASES = {
 }
 
 
+# the served call: layer 2 of a layer-stacked (4, 32, 768, 8, 128) cache,
+# attended over a 640-token window, with the o-bank's layer-stacked banks
+# read in place, at qwen3-1.7b (H 16, d_model 2048) and mistral-7b (H 32,
+# d_model 4096) widths
+SB, SL, SS, SW, SN = 32, 4, 768, 640, 1000
+
+
+def _stacked_attn(s, h):
+    return (s((SB, h, HD), BF16), s((SL, SB, SS, KV, HD), BF16),
+            s((SL, SB, SS, KV, HD), BF16), s((SB,), I32), s((SB,), I32))
+
+
+def _d(h):
+    return {16: 2048, 32: 4096}[h]
+
+
+def _stacked_jd(s, h, sigma_shape):
+    return _stacked_attn(s, h) + (
+        s((SL, 1, _d(h), R), BF16), s((SL, 1, h * HD, R), BF16),
+        s((SL, SN) + sigma_shape, BF16), s((SL, SN), I32))
+
+
+def _stacked(kernel):
+    return lambda *a: kernel(*a, layer=2, window=SW, interpret=False)
+
+
+for _h in (16, 32):
+    CASES[f"fused_decode_jd_full_stacked_h{_h}"] = (
+        _stacked(fused_decode_jd),
+        lambda s, h=_h: _stacked_jd(s, h, (R, R)))
+    CASES[f"fused_decode_jd_diag_stacked_h{_h}"] = (
+        _stacked(fused_decode_jd),
+        lambda s, h=_h: _stacked_jd(s, h, (R,)))
+    CASES[f"fused_decode_lora_stacked_h{_h}"] = (
+        _stacked(fused_decode_lora),
+        lambda s, h=_h: _stacked_attn(s, h) + (
+            s((SL, SN, R, h * HD), BF16), s((SL, SN, _d(h), R), BF16)))
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(one_chip, name):
     kernel, args = CASES[name]
@@ -144,3 +183,65 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
     compiled = jax.jit(kernel).lower(*args(shape)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_step_reads_the_cache_in_place(one_chip, monkeypatch):
+    """The served fused jd decode step (the executor's jitted step, cache
+    donated) at qwen3-1.7b widths and three layers, compiled for a v5e:
+    the kernels read K/V and the o-bank from the layer-stacked arrays, so
+    the program holds no slice, reshape or copy with a bf16 output of a
+    K/V window or more (B x 128 x Kv x hd elements) — not of a window, not
+    of the whole cache — and one kernel per layer."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import transformer as tf
+    from repro.models.param import init_params
+    from repro.serving.real_executor import RealModelExecutor
+
+    cfg = dataclasses.replace(get_config("qwen3-1.7b"), num_layers=3)
+    nl, b, s_max, bucket = cfg.num_layers, 8, 768, 640
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def on_chip(x):
+        return shape(x.shape, x.dtype)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init_params(tf.model_defs(cfg), jax.random.PRNGKey(0))))
+    d, hq, hk = cfg.d_model, cfg.num_heads * HD, cfg.num_kv_heads * HD
+    bundles = {"layers": {
+        t: {"U": shape((nl, 1, do, R), BF16), "V": shape((nl, 1, di, R), BF16),
+            "sigma": shape((nl, SN, R, R), BF16),
+            "cluster_of": shape((nl, SN), I32)}
+        for t, (di, do) in {"q": (d, hq), "k": (d, hk), "v": (d, hk),
+                            "o": (hq, d)}.items()}}
+    ex = RealModelExecutor(cfg, params, bundles, "jd", b, s_max,
+                           decode_path="fused")
+    cache = jax.tree.map(on_chip, ex.cache)
+    # steer the kernels' dispatch to the chip's path: the CPU backend here
+    # would pick the jnp oracles and the Pallas interpreter
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    txt = ex._decode.lower(params, bundles, shape((b, 1), I32), cache,
+                           shape((b,), I32), bucket=bucket).compile().as_text()
+    jax.clear_caches()
+
+    # what the program materializes: the entry computation's instructions
+    # (a slice inside a fusion is read in place)
+    entry = txt.split("\nENTRY", 1)[1].split("\n}\n", 1)[0]
+    limit = b * 128 * cfg.num_kv_heads * HD
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(", line)
+        if not m or m.group(2) not in ("slice", "reshape", "copy",
+                                       "copy-start"):
+            continue
+        for dims in re.findall(r"bf16\[([\d,]*)\]", m.group(1)):
+            n = 1
+            for x in filter(None, dims.split(",")):
+                n *= int(x)
+            assert n < limit, line[:240]
+    kernels = re.findall(r"%fused_decode_jd(?:\.\d+)? = .*custom-call", txt)
+    assert len(kernels) == nl

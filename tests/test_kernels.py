@@ -328,3 +328,87 @@ def test_ops_fused_dispatch_matches_ref():
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
                                rtol=2e-2, atol=2e-2)
     np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_r), **FUSED_TOL)
+    # the served form: the layer-stacked cache and banks, read at a layer
+    # over the first `window` tokens
+    L, li, window = 2, 1, 32
+    kk, kv_, ku, kvb, ksig = jax.random.split(jax.random.PRNGKey(41), 5)
+    ks = jax.random.normal(kk, (L, B, S, Kv, hd), jnp.float32)
+    vs = jax.random.normal(kv_, (L, B, S, Kv, hd), jnp.float32)
+    kv_w = jnp.minimum(kv_len, window)
+    at = dict(layer=li, window=window)
+    As, Bs = jnp.stack([A / 2, A]), jnp.stack([Bm, Bm / 2])
+    o_k, d_k = ops.fused_lora_decode(q, ks, vs, kv_w, ids, As, Bs, **at,
+                                     use_pallas="interpret")
+    o_r, d_r = ops.fused_lora_decode(q, ks, vs, kv_w, ids, As, Bs, **at,
+                                     use_pallas="ref")
+    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
+                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_r), **FUSED_TOL)
+    U = jax.random.normal(ku, (L, 2, d_out, r), jnp.float32) / 4
+    V = jax.random.normal(kvb, (L, 2, H * hd, r), jnp.float32) / 8
+    sig = jax.random.normal(ksig, (L, n, r, r), jnp.float32) / 4
+    cluster_of = jnp.stack([jnp.arange(n, dtype=jnp.int32) % 2,
+                            (jnp.arange(n, dtype=jnp.int32) + 1) % 2])
+    o_k, d_k = ops.fused_jd_decode(q, ks, vs, kv_w, ids, U, V, sig,
+                                   cluster_of, **at, use_pallas="interpret")
+    o_r, d_r = ops.fused_jd_decode(q, ks, vs, kv_w, ids, U, V, sig,
+                                   cluster_of, **at, use_pallas="ref")
+    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
+                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_r), **FUSED_TOL)
+    a_k = ops.decode_attention(q, ks, vs, kv_w, **at, use_pallas="interpret")
+    a_r = ops.decode_attention(q, ks, vs, kv_w, **at, use_pallas="ref")
+    np.testing.assert_allclose(np.asarray(a_k), np.asarray(a_r),
+                               rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("G", [2, 4])
+@pytest.mark.parametrize("mode", ["attn", "lora", "lora_q8", "jd_diag_k1",
+                                  "jd_diag_k3", "jd_full_k1", "jd_full_k3"])
+def test_stacked_cache_bit_exact_with_layer_slice(mode, G):
+    """A call on the layer-stacked (L, B, S, Kv, hd) cache at ``layer``
+    over ``window`` < S tokens, with layer-stacked banks read in place, ==
+    the (B, S, Kv, hd) call on ``k[layer, :, :window]`` with that layer's
+    banks: out (and l, m, delta) bit for bit."""
+    L, B, Kv, hd, S, window, n, r, d_out, li = 3, 3, 2, 32, 96, 64, 5, 8, 64, 1
+    H = G * Kv
+    ks = jax.random.split(jax.random.PRNGKey(50 + G), 8)
+    q = jax.random.normal(ks[0], (B, H, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (L, B, S, Kv, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (L, B, S, Kv, hd), jnp.float32)
+    kv_len = jax.random.randint(ks[3], (B,), 1, window + 1)
+    ids = jax.random.randint(ks[4], (B,), 0, n)
+    k_l, v_l = k[li, :, :window], v[li, :, :window]
+    at = dict(layer=li, window=window, block_s=32)
+
+    def layer_of(banks):
+        return [None if x is None else x[li] for x in banks]
+
+    if mode == "attn":
+        got = flash_decode(q, k, v, kv_len, **at)
+        want = flash_decode(q, k_l, v_l, kv_len, block_s=32)
+    elif mode.startswith("lora"):
+        A = jax.random.normal(ks[5], (L, n, r, H * hd), jnp.float32) / 8
+        Bm = jax.random.normal(ks[6], (L, n, d_out, r), jnp.float32) / 4
+        banks = [A, Bm, None, None]
+        if mode == "lora_q8":
+            (aq, a_s), (bq, b_s) = adapter_quantize(A), adapter_quantize(Bm)
+            banks = [aq, bq, a_s, b_s]
+        got = fused_decode_lora(q, k, v, kv_len, ids, *banks, **at)
+        want = fused_decode_lora(q, k_l, v_l, kv_len, ids,
+                                 *layer_of(banks), block_s=32)
+    else:
+        kc = int(mode[-1])
+        U = jax.random.normal(ks[5], (L, kc, d_out, r), jnp.float32) / 4
+        V = jax.random.normal(ks[6], (L, kc, H * hd, r), jnp.float32) / 8
+        sig = (jnp.abs(jax.random.normal(ks[7], (L, n, r))) if "diag" in mode
+               else jax.random.normal(ks[7], (L, n, r, r)) / 4)
+        cluster_of = (jnp.arange(n, dtype=jnp.int32)[None]
+                      + jnp.arange(L, dtype=jnp.int32)[:, None]) % kc
+        banks = [U, V, sig, cluster_of]
+        got = fused_decode_jd(q, k, v, kv_len, ids, *banks, **at)
+        want = fused_decode_jd(q, k_l, v_l, kv_len, ids, *layer_of(banks),
+                               block_s=32)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
