@@ -1,6 +1,7 @@
 """`fused_decode_jd` (kernels/fused_decode.py): least time (the larger
-of its FLOPs and bytes over the chip's peaks, `bench.costs.fused_decode_call`
-at the attended lengths) over the device time of its trace events (%)."""
+of its FLOPs and bytes over the chip's peaks, the architecture's
+``kernel_calls`` at the attended lengths) over the device time of its trace
+events (%)."""
 from bench import roofline
 
 

@@ -1,9 +1,8 @@
 """Decode step's share of HBM bandwidth: the least bytes each decode step
 must read (weights once, K/V of the attended tokens, the batch's adapter
-factors; `bench.costs.decode_step_bytes`) over the device time of the
-fused decode program (``jit__fused_decode_fn`` in the trace) times the
+factors; the architecture's ``decode_step_bytes``) over the device time of
+the fused decode program (``jit__fused_decode_fn`` in the trace) times the
 chip's peak bandwidth (%)."""
-from bench import costs
 
 PROGRAM = "jit__fused_decode_fn"
 
@@ -13,7 +12,7 @@ def read(rec):
     secs = rec.reduced.get("program_s", {}).get(PROGRAM, 0.0)
     if not steps or secs <= 0:
         return None
-    nbytes = sum(costs.decode_step_bytes(rec.arch, rec.adapters,
-                                         i["kv_lens"], i["ids"])
+    nbytes = sum(rec.arch.decode_step_bytes(rec.adapters, i["kv_lens"],
+                                            i["ids"])
                  for _, _, _, i in steps)
     return 100.0 * nbytes / (secs * rec.peak["hbm_bytes_per_s"])

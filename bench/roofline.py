@@ -1,22 +1,27 @@
-"""Roofline share of a fused decode kernel: the least time of the window's
-kernel calls over the kernel's summed device time (%)."""
+"""Roofline share of a named kernel: the least time of the window's kernel
+calls (the architecture's ``kernel_calls`` at each decode step's attended
+lengths and adapters) over the kernel's summed device time (%)."""
 from bench import costs
 
 
 def share(rec, kernel: str, mode: str):
     if rec.adapters["mode"] != mode:
         return None
-    dev = rec.reduced["kernel_s"].get(kernel, 0.0)
+    dev = rec.reduced["op_s"].get(kernel, 0.0)
     steps = rec.of("decode")
     if dev <= 0 or not steps:
         return None
-    least, bound = 0.0, {}
+    least, calls, bound = 0.0, 0, {}
     for _, _, _, info in steps:
-        fl, nb = costs.fused_decode_call(rec.arch, rec.adapters,
-                                         info["kv_lens"], info["ids"])
-        t, which = costs.least_seconds(fl, nb, rec.peak)
-        least += rec.arch.L * t
-        bound[which] = bound.get(which, 0) + 1
-    rec.notes.append(f"# {kernel}: {len(steps) * rec.arch.L} calls, device "
-                     f"{dev:.6f} s, least {least:.6f} s, bound {bound}")
+        groups = rec.arch.kernel_calls(kernel, rec.adapters, info["kv_lens"],
+                                       info["ids"])
+        if groups is None:
+            return None
+        for fl, nb, n in groups:
+            t, which = costs.least_seconds(fl, nb, rec.peak)
+            least += n * t
+            calls += n
+            bound[which] = bound.get(which, 0) + 1
+    rec.notes.append(f"# {kernel}: {calls} calls, device {dev:.6f} s, "
+                     f"least {least:.6f} s, bound {bound}")
     return 100.0 * least / dev

@@ -29,8 +29,8 @@ import time
 from typing import Callable, Dict, Optional
 
 from bench import correct, spec, stats, traffic
+from bench import trace as trace_mod
 from bench.compile_clock import CompileClock
-from bench.costs import Arch
 
 ROOT = spec.ROOT
 CACHE_DIR = ROOT / ".jax_cache"          # fixed: the path is part of the key
@@ -181,10 +181,12 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     cache = use_compile_cache()
     conf, tr = cell.config, cell.traffic
     ad = tr["adapters"]
-    cfg = spec.model_config(conf, tr)
+    arch = spec.arch(conf["reference"])
+    cfg = arch.model_config(conf, tr)
     init = float(conf["initializer_range"])
     params = weights.make_params(cfg, seed, init)
-    bundles = weights.make_adapters(cfg, ad, seed, init)
+    bundles = weights.make_adapters(cfg, arch.adapter_dims(conf), ad, seed,
+                                    init)
     jax.block_until_ready((params, bundles))
 
     B = int(tr["max_batch"])
@@ -208,7 +210,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     log(f"# set-up {setup_s:.3f} s; compile cache {cache}; {before}")
 
     wave = set(range(loop.next_rid - loop.clients, loop.next_rid))
-    tracer = _Tracer(trace)
+    tracer = _Tracer(trace, ex)
     tracer.start()
     t0 = time.perf_counter()
     if trace:
@@ -241,9 +243,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                   "platform": dev.platform, "kind": dev.device_kind,
                   "count": len(devices), "memory_peak_bytes": peak_bytes}}
 
-    arch = Arch.of(conf)
     if trace:
-        rec = tracer.record(server, t0, t_end, arch, ad, dev.device_kind)
+        rec = tracer.record(server, t0, t_end, arch.arch(conf), ad,
+                            dev.device_kind)
         for m in cell.per_layer:
             v = spec.metric_reader(m["name"]).read(rec)
             if v is not None:
@@ -268,7 +270,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                "tokens": list(server.reqs[i]["tokens"])}
               for i in sent if server.reqs[i]["finished"]]
     # the program's state goes before the reference runs
-    del server, eng, ex
+    del server, eng, ex, tracer
     gc.collect()
     verdict = correct.check(conf, tr, cell.limits, params, bundles, served,
                             seed)
@@ -284,11 +286,13 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
 
 class _Tracer:
-    """Profiler capture of the traced window, and its reduction."""
+    """Profiler capture of the traced window, and its reduction; the
+    executor's programs run in the window give the named scopes."""
 
-    def __init__(self, on: bool):
+    def __init__(self, on: bool, ex):
         self.on = on
         self.path = TRACE_DIR
+        self.programs = trace_mod.ProgramScopes(ex)
         self._window = None
 
     def start(self) -> None:
@@ -296,6 +300,7 @@ class _Tracer:
             return
         import jax
 
+        self.programs.watch()
         shutil.rmtree(self.path, ignore_errors=True)
         jax.profiler.start_trace(str(self.path))
         self._window = jax.profiler.TraceAnnotation("bench.window")
@@ -308,19 +313,33 @@ class _Tracer:
 
         self._window.__exit__(None, None, None)
         jax.profiler.stop_trace()
+        self.programs.unwatch()
 
     def record(self, server, t0, t1, arch, ad, device_kind):
         from bench import peaks
-        from bench import trace as trace_mod
 
         try:
             events = trace_mod.normalize(trace_mod.newest_xplane(self.path))
         finally:
             shutil.rmtree(self.path, ignore_errors=True)
-        return trace_mod.Record(
+        events["scopes"], clashes = self.programs.scopes()
+        rec = trace_mod.Record(
             reduced=trace_mod.reduce(events),
             spans=[s for s in server.spans if s[1] >= t0 and s[2] <= t1],
             arch=arch, adapters=ad, peak=peaks.peaks(device_kind))
+        for prog, by in rec.reduced["scope_s"].items():
+            if prog in events["scopes"]:
+                kernels = sorted({(trace_mod.kernel_of(i), where) for i, where
+                                  in events["scopes"][prog].items()
+                                  if trace_mod.kernel_of(i)})
+                rec.notes.append(f"# scope_s {prog} (program "
+                                 f"{rec.reduced['program_s'].get(prog)} s): "
+                                 f"{json.dumps(by, sort_keys=True)}; "
+                                 f"kernels in {kernels}")
+        if clashes:
+            rec.notes.append(f"# scopes: {clashes} instructions in "
+                             f"different scopes in programs of one name")
+        return rec
 
 
 def main(argv=None) -> None:

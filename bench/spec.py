@@ -7,16 +7,22 @@ configuration and traffic mix, and everything else is found by name.
 * the limits that decide ``correct`` for cell ``<w>``: ``bench/limits/<w>.json``;
 * per-layer metric ``<m>``: ``bench/metrics/<m>.py``, whose ``read(rec)``
   returns a number or None;
-* plain reference ``<r>``: ``bench/references/<r>.py``.
+* plain reference ``<r>``: ``bench/references/<r>.py``;
+* architecture ``<r>``, the same name as the configuration's reference:
+  ``bench/archs/<r>.py``, whose ``model_config(conf, traffic)`` gives the
+  program's `ModelConfig`, ``adapter_dims(conf)`` the adapter targets'
+  ``{target: (d_in, d_out)}`` and ``arch(conf)`` the cost object that the
+  per-layer metrics read (``rec.arch``).
 
-A later change adds a cell, a mix or a metric as new files and entries,
-without editing any file that is already here.
+A later change adds a cell, a mix, a metric or an architecture as new files
+and entries, without editing any file that is already here.
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from types import ModuleType
 from typing import Dict
@@ -33,6 +39,9 @@ def _load_json(path: Path) -> Dict:
 def _load_module(path: Path, name: str) -> ModuleType:
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    # registered first, as an import would: a dataclass in the module
+    # looks its module up by name
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
 
@@ -83,23 +92,12 @@ def reference(name: str) -> ModuleType:
                         "bench_reference_" + name)
 
 
-def model_config(conf: Dict, traffic: Dict):
-    """The program's `ModelConfig` for a configuration file, keyed by the
-    published ``config.json`` names."""
-    from repro.configs.base import LoRAConfig, ModelConfig
+def arch(name: str) -> ModuleType:
+    return _load_module(BENCH_DIR / "archs" / f"{name}.py",
+                        "bench_arch_" + name)
 
-    prog = conf["program"]
-    ad = traffic["adapters"]
-    return ModelConfig(
-        name=conf["name"], family=prog["family"],
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim")
-        or conf["hidden_size"] // conf["num_attention_heads"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        qk_norm=prog["qk_norm"], rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        sliding_window=conf.get("sliding_window") or 0,
-        lora=LoRAConfig(rank=ad["rank"], targets=tuple(ad["targets"])))
+
+def model_config(conf: Dict, traffic: Dict):
+    """The program's `ModelConfig` for a configuration file, by its
+    architecture's module."""
+    return arch(conf["reference"]).model_config(conf, traffic)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 BF16 = jnp.bfloat16
 _BLOCK_ELEMS = 1 << 25       # f32 elements drawn at once: 128 MiB
 _PARAMS, _ADAPTERS = 0, 1
+_STACKS = 1 << 16         # keys of the adapter stacks after the first
 
 
 def key_from_seed(seed: int) -> jax.Array:
@@ -66,12 +67,6 @@ def make_params(cfg, seed: int, init_range: float) -> Dict:
     return jax.tree.unflatten(treedef, gen(key))
 
 
-def adapter_dims(cfg) -> Dict[str, tuple]:
-    d, hd = cfg.d_model, cfg.resolved_head_dim
-    return {"q": (d, cfg.num_heads * hd), "k": (d, cfg.num_kv_heads * hd),
-            "v": (d, cfg.num_kv_heads * hd), "o": (cfg.num_heads * hd, d)}
-
-
 def adapter_stds(rank: int, relative: float, init_range: float) -> Dict:
     """Factor stds that give each adapter's delta W elements of std
     ``relative * init_range``: raw LoRA dW = B A sums ``rank`` products;
@@ -80,17 +75,31 @@ def adapter_stds(rank: int, relative: float, init_range: float) -> Dict:
     return {"factor": f, "sigma": 1.0 / math.sqrt(rank)}
 
 
-def make_adapters(cfg, adapters: Dict, seed: int, init_range: float) -> Dict:
+def adapter_stacks(cfg) -> Dict[str, tuple]:
+    """The program's adapter stacks and the leading axes of each
+    (`transformer.lora_defs_tree`): ``{"layers": (L,)}`` for a dense model;
+    a model whose layers come in kinds has a stack of each."""
+    from repro.models import transformer as tf
+    from repro.models.param import is_def
+
+    return {key: tuple(jax.tree.leaves(per, is_leaf=is_def)[0].shape[:-2])
+            for key, per in tf.lora_defs_tree(cfg).items()}
+
+
+def make_adapters(cfg, dims: Dict[str, tuple], adapters: Dict, seed: int,
+                  init_range: float) -> Dict:
     """The adapter collection in the layout `RealModelExecutor` takes:
-    ``{"layers": {target: {...}}}`` with a leading layer axis on every leaf.
+    ``{stack: {target: {...}}}`` for each of the program's adapter stacks
+    (`adapter_stacks`; ``"layers"`` with a leading layer axis L for a dense
+    model), each target ``t`` of shape ``dims[t] = (d_in, d_out)`` (the
+    architecture module's ``adapter_dims``).
 
     ``lora``: A (L, n, r, d_in), B (L, n, d_out, r), one pair per adapter.
     ``jd``: one shared basis per cluster, U (L, k, d_out, r) and
     V (L, k, d_in, r), a full Sigma (L, n, r, r) per adapter, and
     ``cluster_of`` (L, n) with adapter i in cluster ``i % k``."""
     mode, n, r = adapters["mode"], adapters["count"], adapters["rank"]
-    L = cfg.num_layers
-    dims = adapter_dims(cfg)
+    stacks = adapter_stacks(cfg)
     std = adapter_stds(r, adapters["relative_size"], init_range)
     targets = list(adapters["targets"])
     if mode == "jd" and adapters.get("sigma", "full") != "full":
@@ -99,21 +108,28 @@ def make_adapters(cfg, adapters: Dict, seed: int, init_range: float) -> Dict:
         raise ValueError(f"unknown adapter mode {mode!r}")
     k = int(adapters.get("clusters", 1))
 
-    @jax.jit
-    def gen(key):
+    def stack(key, L):
         out = {}
         for i, t in enumerate(targets):
             di, do = dims[t]
             ka, kb, ks = jax.random.split(jax.random.fold_in(key, i), 3)
             if mode == "lora":
-                out[t] = {"A": _normal(ka, (L, n, r, di), std["factor"]),
-                          "B": _normal(kb, (L, n, do, r), std["factor"])}
+                out[t] = {"A": _normal(ka, L + (n, r, di), std["factor"]),
+                          "B": _normal(kb, L + (n, do, r), std["factor"])}
             else:
                 cl = jnp.arange(n, dtype=jnp.int32) % k
-                out[t] = {"U": _normal(ka, (L, k, do, r), std["factor"]),
-                          "V": _normal(kb, (L, k, di, r), std["factor"]),
-                          "sigma": _normal(ks, (L, n, r, r), std["sigma"]),
-                          "cluster_of": jnp.broadcast_to(cl, (L, n))}
-        return {"layers": out}
+                out[t] = {"U": _normal(ka, L + (k, do, r), std["factor"]),
+                          "V": _normal(kb, L + (k, di, r), std["factor"]),
+                          "sigma": _normal(ks, L + (n, r, r), std["sigma"]),
+                          "cluster_of": jnp.broadcast_to(cl, L + (n,))}
+        return out
+
+    @jax.jit
+    def gen(key):
+        # the first stack draws from the key itself, each later one from
+        # a key of its own
+        return {name: stack(key if j == 0 else jax.random.fold_in(
+                    key, _STACKS + j), L)
+                for j, (name, L) in enumerate(stacks.items())}
 
     return gen(jax.random.fold_in(key_from_seed(seed), _ADAPTERS))

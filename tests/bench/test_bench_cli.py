@@ -32,6 +32,16 @@ def test_every_cell_resolves_to_its_files():
         for m in cell.per_layer:
             assert callable(spec.metric_reader(m["name"]).read)
         spec.reference(cell.config["reference"])
+        arch = spec.arch(cell.config["reference"])
+        assert set(arch.adapter_dims(cell.config)) >= set(
+            cell.traffic["adapters"]["targets"])
+        assert spec.model_config(cell.config, cell.traffic).num_layers == \
+            cell.config["num_hidden_layers"]
+        for m in cell.per_layer:
+            if m["name"].endswith("_roofline"):
+                kernel = m["name"][:-len("_roofline")]
+                assert arch.arch(cell.config).kernel_calls(
+                    kernel, cell.traffic["adapters"], [1], [0]), kernel
     for c in bench["configs"]:
         conf = json.loads((spec.ROOT / c["file"]).read_text())
         assert conf["name"] == c["name"]

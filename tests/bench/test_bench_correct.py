@@ -97,6 +97,16 @@ def test_each_fault_is_not_correct(tiny_cell, fault):
         res["checks"]["widest_gap"]["limit"]
 
 
+@pytest.mark.parametrize("fault", [state_unchanged, half_batch_left_out,
+                                   token_altered, adapters_misrouted])
+def test_each_fault_is_not_correct_with_raw_lora(tiny_cell, fault):
+    """The same faults under raw LoRA banks, the lora cell's adapters."""
+    res = serve(tiny_cell("lora"), 4, plant=fault)
+    assert not res["correct"], res["checks"]
+    assert res["checks"]["widest_gap"]["value"] > \
+        res["checks"]["widest_gap"]["limit"]
+
+
 def test_a_request_of_the_wrong_length_fails(tiny_cell, monkeypatch):
     """A finished request must carry exactly the mix's output length."""
     cell = tiny_cell("jd")

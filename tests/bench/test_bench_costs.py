@@ -1,11 +1,13 @@
 """The FLOP and byte counts of the yardstick, against counts made by hand
-for one layer of each configuration."""
+for one layer of each configuration, through the configurations'
+architecture module (`bench/archs/dense_gqa.py`)."""
 import pytest
 
 from bench import costs, spec
 
-QWEN = costs.Arch.of(spec.cell("qwen3-1.7b.jd1000.decode").config)
-MISTRAL = costs.Arch.of(spec.cell("mistral-7b-16l.jd1000.decode").config)
+DENSE = spec.arch("dense_gqa")
+QWEN = DENSE.arch(spec.cell("qwen3-1.7b.jd1000.decode").config)
+MISTRAL = DENSE.arch(spec.cell("mistral-7b-16l.jd1000.decode").config)
 JD = {"mode": "jd", "rank": 16, "targets": ["q", "k", "v", "o"], "clusters": 1}
 LORA = {"mode": "lora", "rank": 16, "targets": ["q", "k", "v", "o"]}
 
@@ -32,7 +34,7 @@ def test_adapter_flops_by_hand():
 
 def test_fused_jd_call_by_hand():
     # one request attending 513 tokens, adapter 5, one layer of qwen3
-    flops, nbytes = costs.fused_decode_call(QWEN, JD, [513], [5])
+    flops, nbytes = QWEN.fused_decode_call(JD, [513], [5])
     assert flops == 4 * 2048 * 513 + (2 * 16 * 4096 + 2 * 256)
     kv = 2 * 513 * 8 * 128 * 2                    # K and V, bf16
     q_out = 2 * 2048 * 2                          # q in, attention out
@@ -42,8 +44,8 @@ def test_fused_jd_call_by_hand():
 
 
 def test_fused_lora_call_counts_each_adapter_once():
-    one = costs.fused_decode_call(MISTRAL, LORA, [100, 100], [3, 3])[1]
-    two = costs.fused_decode_call(MISTRAL, LORA, [100, 100], [3, 4])[1]
+    one = MISTRAL.fused_decode_call(LORA, [100, 100], [3, 3])[1]
+    two = MISTRAL.fused_decode_call(LORA, [100, 100], [3, 4])[1]
     assert two - one == 16 * (4096 + 4096) * 2
 
 
@@ -52,11 +54,11 @@ def test_decode_step_by_hand():
     per_tok = 16 * (2 * 218_103_808 + costs.adapter_token_flops(
         MISTRAL, JD, JD["targets"])) + 2 * 4096 * 32000
     attn = 16 * 4 * 4096 * (600 + 700)
-    assert costs.decode_step_flops(MISTRAL, JD, kv) == 2 * per_tok + attn
+    assert MISTRAL.decode_step_flops(JD, kv) == 2 * per_tok + attn
     weights = (16 * 218_103_808 + 4096 * 32000) * 2
     kvb = 16 * 2 * (600 + 700) * 8 * 128 * 2
     ad = 16 * costs.adapter_layer_bytes(MISTRAL, JD, JD["targets"], [1, 2])
-    assert costs.decode_step_bytes(MISTRAL, JD, kv, [1, 2]) == \
+    assert MISTRAL.decode_step_bytes(JD, kv, [1, 2]) == \
         weights + kvb + ad
 
 
@@ -65,7 +67,7 @@ def test_prefill_by_hand():
     per_tok = 2 * 50_331_648 + costs.adapter_token_flops(QWEN, JD,
                                                          JD["targets"])
     causal = 4 * 2048 * (P * (P + 1) // 2)
-    assert costs.prefill_flops(QWEN, JD, P) == \
+    assert QWEN.prefill_flops(JD, P) == \
         28 * (P * per_tok + causal) + 2 * 2048 * 151936
 
 

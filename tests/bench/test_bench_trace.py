@@ -97,7 +97,7 @@ def test_a_trace_without_a_window_or_device_is_refused():
 def record(spans, adapters=None):
     conf = spec.cell("qwen3-1.7b.jd1000.decode").config
     return trace.Record(reduced=trace.reduce(E), spans=spans,
-                        arch=costs.Arch.of(conf),
+                        arch=spec.arch("dense_gqa").arch(conf),
                         adapters=adapters or {"mode": "jd", "rank": 16,
                                               "targets": ["q", "k", "v", "o"]},
                         peak={"flops_per_s": 197e12,
@@ -122,15 +122,15 @@ def test_readers_on_the_hand_made_trace():
     assert read("executor.decode_step_ms", rec) == pytest.approx(3.0)
     a = rec.arch
     ad = rec.adapters
-    least = sum(a.L * costs.least_seconds(*costs.fused_decode_call(
-        a, ad, kv, [1, 2]), rec.peak)[0] for kv in ([513] * 2, [514] * 2))
+    least = sum(a.L * costs.least_seconds(*a.fused_decode_call(
+        ad, kv, [1, 2]), rec.peak)[0] for kv in ([513] * 2, [514] * 2))
     assert read("fused_decode_jd_roofline", rec) == \
         pytest.approx(100 * least / 3500e-9)
-    flops = costs.prefill_flops(a, ad, 512) + sum(
-        costs.decode_step_flops(a, ad, kv) for kv in ([513] * 2, [514] * 2))
+    flops = a.prefill_flops(ad, 512) + sum(
+        a.decode_step_flops(ad, kv) for kv in ([513] * 2, [514] * 2))
     assert read("step.mfu", rec) == pytest.approx(
         100 * flops / (10000e-9 * 197e12))
-    nbytes = sum(costs.decode_step_bytes(a, ad, kv, [1, 2])
+    nbytes = sum(a.decode_step_bytes(ad, kv, [1, 2])
                  for kv in ([513] * 2, [514] * 2))
     # over the fused decode program's device time, not the host spans
     assert read("step.decode_hbm_share", rec) == pytest.approx(
@@ -201,3 +201,94 @@ def test_a_recorded_v5e_trace():
     assert r["kernel_s"]["fused_decode_jd"] < step <= r["busy_s"]
     assert {g[0] for g in r["breakdown"]["idle_gaps"]} <= {
         "engine", "decode", "prefill", "client", "outside_spans"}
+
+
+# Each reader's value on the recorded v5e trace (its decode steps given
+# kv_lens 600-631 and adapters i % 7) and on the hand-made trace, with the
+# cost objects of both configurations, as the readers read them before the
+# costs moved into bench/archs/dense_gqa.py.
+PINNED = {
+  "v5e": {
+    "qwen3-1.7b.jd1000.decode": {
+        "device.idle_share": 12.22556559938065,
+        "engine.host_ms_per_step": 0.26081449999998396,
+        "executor.prefill_ms": None,
+        "executor.decode_step_ms": 29.391273249999962,
+        "fused_decode_jd_roofline": 46.211949041661725,
+        "step.mfu": 3.7386164659745442,
+        "step.decode_hbm_share": 51.03936840001938,
+    },
+    "mistral-7b-16l.jd1000.decode": {
+        "device.idle_share": 12.22556559938065,
+        "engine.host_ms_per_step": 0.26081449999998396,
+        "executor.prefill_ms": None,
+        "executor.decode_step_ms": 29.391273249999962,
+        "fused_decode_jd_roofline": 26.61960533563492,
+        "step.mfu": 7.712791472022136,
+        "step.decode_hbm_share": 76.34963100750282,
+    },
+  },
+  "hand_made": {
+    "qwen3-1.7b.jd1000.decode": {
+        "device.idle_share": 40.0,
+        "engine.host_ms_per_step": 2.5000000000000004,
+        "executor.prefill_ms": 3.0,
+        "executor.decode_step_ms": 2.9999999999999996,
+        "fused_decode_jd_roofline": 8540.08498168498,
+        "step.mfu": 75875.49329543146,
+        "step.decode_hbm_share": 174439.38774114772,
+    },
+    "mistral-7b-16l.jd1000.decode": {
+        "device.idle_share": 40.0,
+        "engine.host_ms_per_step": 2.5000000000000004,
+        "executor.prefill_ms": 3.0,
+        "executor.decode_step_ms": 2.9999999999999996,
+        "fused_decode_jd_roofline": 5062.9503575789295,
+        "step.mfu": 185007.9616649746,
+        "step.decode_hbm_share": 357629.7119413919,
+    },
+  },
+}
+JD = {"mode": "jd", "rank": 16, "targets": ["q", "k", "v", "o"], "clusters": 1}
+
+
+def _recorded_v5e():
+    import json
+    from pathlib import Path
+
+    ev = json.loads((Path(__file__).parent / "data" /
+                     "trace_v5e_decode.json").read_text())
+    kinds = {"bench.decode": "decode", "bench.prefill": "prefill",
+             "bench.engine": "engine"}
+    info = {"decode": {"kv_lens": [600 + i for i in range(32)],
+                       "ids": [i % 7 for i in range(32)]},
+            "prefill": {"prompt_len": 512, "adapter": 3}, "engine": {}}
+    spans = [(kinds[n], s / 1e9, (s + d) / 1e9, info[kinds[n]])
+             for n, s, d in ev["host"] if n in kinds]
+    return ev, spans
+
+
+HAND_MADE_SPANS = [
+    ("engine", 0.0, 0.010, {}),
+    ("prefill", 0.001, 0.004, {"prompt_len": 512, "adapter": 1}),
+    ("decode", 0.005, 0.008, {"kv_lens": [513, 513], "ids": [1, 2]}),
+    ("engine", 0.010, 0.014, {}),
+    ("decode", 0.010, 0.013, {"kv_lens": [514, 514], "ids": [1, 2]})]
+
+
+@pytest.mark.parametrize("source", ["v5e", "hand_made"])
+@pytest.mark.parametrize("cell", ["qwen3-1.7b.jd1000.decode",
+                                  "mistral-7b-16l.jd1000.decode"])
+def test_readers_read_what_they_read_before(source, cell):
+    ev, spans = _recorded_v5e() if source == "v5e" else (E, HAND_MADE_SPANS)
+    conf = spec.cell(cell).config
+    rec = trace.Record(reduced=trace.reduce(ev), spans=spans,
+                       arch=spec.arch(conf["reference"]).arch(conf),
+                       adapters=JD, peak={"flops_per_s": 197e12,
+                                          "hbm_bytes_per_s": 819e9})
+    for name, want in PINNED[source][cell].items():
+        got = read(name, rec)
+        if want is None:
+            assert got is None, name
+        else:
+            assert got == pytest.approx(want, rel=1e-12), name

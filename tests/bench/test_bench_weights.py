@@ -49,7 +49,8 @@ def test_adapter_layout(mode, tiny_cell):
     cell = tiny_cell(mode)
     cfg = spec.model_config(cell.config, cell.traffic)
     ad = cell.traffic["adapters"]
-    b = weights.make_adapters(cfg, ad, 3, 0.02)["layers"]
+    dims = spec.arch("dense_gqa").adapter_dims(cell.config)
+    b = weights.make_adapters(cfg, dims, ad, 3, 0.02)["layers"]
     L, n, r = cfg.num_layers, ad["count"], ad["rank"]
     d, q, kv = cfg.d_model, cfg.num_heads * cfg.resolved_head_dim, \
         cfg.num_kv_heads * cfg.resolved_head_dim
