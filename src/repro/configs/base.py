@@ -16,6 +16,11 @@ def _pad_to(x: int, m: int) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed + shared experts.  The router scores all ``num_experts``;
+    the layer holds ``held_experts`` of them (0: all), experts
+    ``[expert_offset, expert_offset + held)``, and computes their part of
+    the result: one device's share of an expert-parallel deployment.
+    ``norm_topk_prob`` renormalises the top-k weights to sum to 1."""
     num_experts: int
     top_k: int
     num_shared: int = 0
@@ -24,6 +29,13 @@ class MoEConfig:
     d_ff_dense: int = 0             # their hidden dim
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
+    norm_topk_prob: bool = True
+    held_experts: int = 0
+    expert_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.held_experts or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,7 +139,7 @@ class ModelConfig:
         L = self.num_layers
         if self.family == "moe":
             m = self.moe
-            per_moe = attn + 3 * d * m.d_ff_expert * (m.num_experts + m.num_shared) \
+            per_moe = attn + 3 * d * m.d_ff_expert * (m.held + m.num_shared) \
                 + d * m.num_experts
             n += (L - m.first_k_dense) * per_moe
             n += m.first_k_dense * (attn + 3 * d * m.d_ff_dense)

@@ -56,6 +56,7 @@ def smoke_config(name: str) -> ModelConfig:
         head_dim=32,
         d_ff=256,
         vocab_size=512,
+        norm_eps=1e-5,      # one eps for every smoke config
         attn_chunk_q=32,
         attn_chunk_kv=32,
         logits_chunk_vocab=0,

@@ -1,19 +1,29 @@
 """Mixture-of-Experts block: shared experts + routed top-k experts.
 
+The router scores every one of ``num_experts`` experts (float32 softmax,
+top-k, renormalised only where ``norm_topk_prob``); a layer holds
+``MoEConfig.held`` of them, ``[expert_offset, expert_offset + held)``.
+
 Implementations:
 
-- ``dense``:  every expert processes every token, masked combine.  Exact
-  oracle; used for CPU smoke tests and as the correctness reference for the
-  distributed paths (tiny configs only — compute is O(E) per token).
-- ``ep``:     shard_map expert-parallel production path.  Router runs in
+- held-expert layer (one device): computes its held experts' part of the
+  result for the (token, expert) pairs routed to them, with no capacity
+  and no drops.  Pairs routed elsewhere add nothing: on one chip of an
+  expert-parallel deployment they are the other chips' part.  Prefill
+  groups the pairs by expert (``_moe_grouped``, a ragged matmul over the
+  held experts); a decode step, a few tokens, runs every held expert on
+  every token (``_moe_dense``), which reads each expert's weights once.
+  Holding every expert, it is the whole layer.
+- ``ep``:     shard_map expert-parallel training path.  Router runs in
   plain SPMD; dispatch/compute/combine run per-device with static capacity
   buffers; partial outputs are summed with a ``psum`` over the model axis.
   Works with expert-sharded weights when ``E % model == 0`` (deepseek) and
   falls back to ff-sharded weights otherwise (granite's 40 experts on a
-  16-way axis).  An all-to-all variant is a recorded §Perf hillclimb.
+  16-way axis).  Token dropping follows the standard static-capacity
+  discipline (capacity_factor in the config); dropped tokens fall through
+  on the residual.  It holds every expert.
 
-Token dropping follows the standard static-capacity discipline
-(capacity_factor in the config); dropped tokens fall through on the residual.
+Named scopes: ``router``, ``experts`` and ``shared``.
 """
 from __future__ import annotations
 
@@ -33,9 +43,10 @@ Array = jax.Array
 def moe_defs(cfg: ModelConfig) -> Dict:
     d = cfg.d_model
     m = cfg.moe
-    E, f = m.num_experts, m.d_ff_expert
+    E, f = m.held, m.d_ff_expert
     defs = {
-        "router": ParamDef((d, E), ("d_model", "experts"), scale=0.02),
+        "router": ParamDef((d, m.num_experts), ("d_model", "experts"),
+                           scale=0.02),
         "w_gate": ParamDef((E, d, f), ("experts", "d_model", "expert_ff")),
         "w_up": ParamDef((E, d, f), ("experts", "d_model", "expert_ff")),
         "w_down": ParamDef((E, f, d), ("experts", "expert_ff", "d_model")),
@@ -52,7 +63,8 @@ def _route(p: Dict, x: Array, cfg: ModelConfig) -> Tuple[Array, Array, Array]:
                         p["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     topw, topi = jax.lax.top_k(probs, m.top_k)
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    if m.norm_topk_prob:
+        topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
     # switch-style load-balancing aux loss
     E = m.num_experts
     dispatch = jax.nn.one_hot(topi[:, 0], E, dtype=jnp.float32)
@@ -62,24 +74,54 @@ def _route(p: Dict, x: Array, cfg: ModelConfig) -> Tuple[Array, Array, Array]:
     return topw, topi.astype(jnp.int32), aux
 
 
+def _held_choices(topi: Array, cfg: ModelConfig) -> Array:
+    """(T, k, held) bool: choice j of token t is held expert e."""
+    m = cfg.moe
+    return (topi - m.expert_offset)[..., None] == jnp.arange(m.held)
+
+
+def routed_counts(topi: Array, cfg: ModelConfig) -> Array:
+    """(held,) int32: the tokens routed to each held expert."""
+    return _held_choices(topi, cfg).sum((0, 1), dtype=jnp.int32)
+
+
 # ---------------------------------------------------------------------------
-# dense oracle
+# held-expert layer (one device)
 # ---------------------------------------------------------------------------
 
 
 def _moe_dense(p: Dict, x: Array, topw: Array, topi: Array, cfg: ModelConfig
                ) -> Array:
-    """(T, d) tokens; computes every expert then combines.  Oracle only."""
-    m = cfg.moe
-    E = m.num_experts
+    """(T, d) tokens; every held expert on every token, combined with the
+    routed weights (zero where not routed)."""
     g = jnp.einsum("td,edf->tef", x, p["w_gate"])
     u = jnp.einsum("td,edf->tef", x, p["w_up"])
     h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-    y_all = jnp.einsum("tef,efd->ted", h, p["w_down"])   # (T, E, d)
-    w_full = jnp.zeros((x.shape[0], E), x.dtype)
-    w_full = w_full.at[jnp.arange(x.shape[0])[:, None], topi].set(
-        topw.astype(x.dtype))
-    return jnp.einsum("ted,te->td", y_all, w_full)
+    y_all = jnp.einsum("tef,efd->ted", h, p["w_down"])   # (T, held, d)
+    w_held = jnp.where(_held_choices(topi, cfg), topw[..., None], 0).sum(1)
+    return jnp.einsum("ted,te->td", y_all, w_held.astype(x.dtype))
+
+
+def _moe_grouped(p: Dict, x: Array, topw: Array, topi: Array,
+                 cfg: ModelConfig) -> Array:
+    """(T, d) tokens; each held expert on the tokens routed to it: the
+    (token, choice) pairs sorted by held expert, one ragged matmul over
+    the groups, and each pair's output weighted back onto its token.
+    Pairs routed elsewhere sort last, outside every group."""
+    m = cfg.moe
+    T, k = topi.shape
+    local = topi.reshape(-1) - m.expert_offset
+    held = (local >= 0) & (local < m.held)
+    order = jnp.argsort(jnp.where(held, local, m.held), stable=True)
+    sizes = jnp.bincount(jnp.where(held, local, m.held),
+                         length=m.held + 1)[:m.held].astype(jnp.int32)
+    xs = x[order // k]
+    g = jax.lax.ragged_dot(xs, p["w_gate"], sizes)
+    u = jax.lax.ragged_dot(xs, p["w_up"], sizes)
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+    y = jax.lax.ragged_dot(h, p["w_down"], sizes).astype(jnp.float32)
+    y = jnp.where(held[order, None], y * topw.reshape(-1)[order, None], 0.0)
+    return y[jnp.argsort(order)].reshape(T, k, -1).sum(1).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -183,18 +225,44 @@ def _moe_ep(p: Dict, x: Array, topw: Array, topi: Array, cfg: ModelConfig,
     )(x, topw, topi, p["w_gate"], p["w_up"], p["w_down"])
 
 
-def moe_fwd(p: Dict, x: Array, cfg: ModelConfig) -> Tuple[Array, Array]:
-    """Full MoE layer on (B, S, d).  Returns (y, aux_loss)."""
-    B, S, d = x.shape
-    m = cfg.moe
-    xt = x.reshape(B * S, d)
-    topw, topi, aux = _route(p, xt, cfg)
-    mesh = current_mesh()
-    if mesh is not None and "model" in mesh.axis_names:
-        y = _moe_ep(p, xt, topw, topi, cfg, mesh)
-    else:
-        y = _moe_dense(p, xt, topw, topi, cfg)
-    y = y.reshape(B, S, d)
-    if m.num_shared:
-        y = y + mlp_fwd(p["shared"], x)
+def _experts(p: Dict, xt: Array, cfg: ModelConfig, every_token: bool
+             ) -> Tuple[Array, Array, Array]:
+    """Routed part of the layer on (T, d) tokens: (y, topi, aux_loss)."""
+    with jax.named_scope("router"):
+        topw, topi, aux = _route(p, xt, cfg)
+    with jax.named_scope("experts"):
+        mesh = current_mesh()
+        if mesh is not None and "model" in mesh.axis_names:
+            if cfg.moe.held != cfg.moe.num_experts:
+                raise NotImplementedError("the expert-parallel path holds "
+                                          "every expert")
+            y = _moe_ep(p, xt, topw, topi, cfg, mesh)
+        elif every_token:
+            y = _moe_dense(p, xt, topw, topi, cfg)
+        else:
+            y = _moe_grouped(p, xt, topw, topi, cfg)
+    return y, topi, aux
+
+
+def _with_shared(p: Dict, x: Array, y: Array, cfg: ModelConfig) -> Array:
+    y = y.reshape(x.shape)
+    if cfg.moe.num_shared:
+        with jax.named_scope("shared"):
+            y = y + mlp_fwd(p["shared"], x)
+    return y
+
+
+def moe_fwd(p: Dict, x: Array, cfg: ModelConfig, *,
+            every_token: bool = False) -> Tuple[Array, Array]:
+    """Full MoE layer on (B, S, d).  Returns (y, aux_loss).
+    ``every_token`` (decode) runs every held expert on every token."""
+    y, _, aux = _experts(p, x.reshape(-1, x.shape[-1]), cfg, every_token)
+    y = _with_shared(p, x, y, cfg)
     return constrain(y, "batch", "seq", "d_model"), aux
+
+
+def moe_decode(p: Dict, x: Array, cfg: ModelConfig) -> Tuple[Array, Array]:
+    """One decode step's MoE layer on (B, S, d), as ``moe_fwd`` with
+    ``every_token``: (y, the tokens routed to each held expert (held,))."""
+    y, topi, _ = _experts(p, x.reshape(-1, x.shape[-1]), cfg, True)
+    return _with_shared(p, x, y, cfg), routed_counts(topi, cfg)

@@ -185,11 +185,15 @@ def _dense_block(p, x, cfg, *, positions, mode, kv, lora_ctx, causal=True):
 
 
 def _moe_block(p, x, cfg, *, positions, mode, kv, lora_ctx):
-    h, new_kv = attention_fwd(p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps),
-                              cfg, positions=positions, mode=mode, cache=kv,
-                              lora_ctx=lora_ctx)
-    x = x + h
-    y, aux = moe_fwd(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
+    with jax.named_scope("attention"):
+        h, new_kv = attention_fwd(p["attn"],
+                                  rms_norm(x, p["ln1"], cfg.norm_eps), cfg,
+                                  positions=positions, mode=mode, cache=kv,
+                                  lora_ctx=lora_ctx)
+        x = x + h
+    with jax.named_scope("moe"):
+        y, aux = moe_fwd(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg,
+                         every_token=mode == "decode")
     return x + y, new_kv, aux
 
 

@@ -40,7 +40,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.adapter_quant import adapter_quantize
-from repro.models import layers
+from repro.models import layers, moe
 from repro.models import transformer as tf
 from repro.models.lora import LoRAContext
 from repro.serving import telemetry
@@ -60,6 +60,9 @@ class RealModelExecutor:
         """bundles: layer-structured arrays for the adapters:
         mode 'lora': {"layers": {target: {"A": (L,n,r,d), "B": (L,n,d,r)}}}
         mode 'jd':   {"layers": {target: {"U","V","sigma","cluster_of"}}}
+        (an MoE model's leading dense layers in a "dense_layers" stack
+        beside them, as `transformer.lora_defs_tree` has it; the fused
+        paths join the two, :func:`_one_stack`)
         ``seed`` keys the synthetic prompts of :meth:`prompt_for`."""
         if decode_path not in DECODE_PATHS:
             raise ValueError(f"decode_path must be one of {DECODE_PATHS}, "
@@ -87,6 +90,9 @@ class RealModelExecutor:
             self._decode = jax.jit(self._decode_fn)
         else:
             self._check_fusable()
+            # one adapter stack by cache layer, as a dense model's: the
+            # fused kernel reads the o-bank in place at the cache's layer
+            bundles = _one_stack(bundles)
             if decode_path == "fused_q8":
                 bundles = _quantize_bundles(bundles, mode)
             self.bundles = bundles
@@ -102,7 +108,7 @@ class RealModelExecutor:
             nbytes // max(n_adapters, 1), 1)
 
     def _check_fusable(self) -> None:
-        if self.cfg.family not in ("dense", "vlm"):
+        if self.cfg.family not in ("dense", "vlm", "moe"):
             raise ValueError("fused decode paths support dense-attention "
                              f"families only, not {self.cfg.family!r}")
         if self.cfg.sliding_window:
@@ -128,6 +134,8 @@ class RealModelExecutor:
     def _prefill_fn(self, params, bundles, tokens, cache, ids):
         if self.decode_path == "fused_q8":
             bundles = _dequantize_bundles(bundles)
+        if self.decode_path != "unfused" and self.cfg.family == "moe":
+            bundles = _split_stack(bundles, self.cfg.moe.first_k_dense)
         proto = self._ctx(ids)
         return tf.prefill(params, {"tokens": tokens}, self.cfg, cache,
                           lora_params=bundles, lora_ctx_proto=proto)
@@ -145,6 +153,17 @@ class RealModelExecutor:
         need = self._host_len + 1
         return min(self.s_max, 128 * -(-need // 128))
 
+    def _stacks(self):
+        """The layer stacks in order, ``(name, first cache layer, count)``:
+        a dense model's one, an MoE model's leading dense layers and then
+        its expert layers (`transformer.model_defs`)."""
+        cfg = self.cfg
+        if cfg.family != "moe":
+            return [("layers", 0, cfg.num_layers)]
+        fk = cfg.moe.first_k_dense
+        return ([("dense_layers", 0, fk)] if fk else []) + [
+            ("layers", fk, cfg.num_layers - fk)]
+
     def _fused_decode_fn(self, params, bundles, tokens, cache, ids, *,
                          bucket):
         """Unrolled single-token decode with the o-projection adapter delta
@@ -152,7 +171,9 @@ class RealModelExecutor:
         semantics (scalar cache index, decode at max occupied length);
         ``bucket`` (static) truncates attention to the occupied KV prefix
         — masked tail blocks contribute exactly zero, so logits are
-        unchanged."""
+        unchanged.  An MoE model's expert layers run every held expert on
+        every token (`moe.moe_decode`), and the step also returns the
+        tokens routed to each held expert, (expert layers, held) int32."""
         cfg = self.cfg
         quant = self.decode_path == "fused_q8"
         banks = bundles["layers"]
@@ -176,44 +197,58 @@ class RealModelExecutor:
                                       cfg.rope_theta)
         ck, cv = cache["k"], cache["v"]
         kv_len = jnp.broadcast_to(idx + S, (Bt,)).astype(jnp.int32)
-        for li in range(cfg.num_layers):
-            p_l = jax.tree.map(lambda a: a[li], params["layers"])
-            lora_l = {t: jax.tree.map(lambda a: a[li], tp)
-                      for t, tp in qkv_banks.items()} or None
-            ctx = (LoRAContext(mode=proto.mode, params=lora_l, ids=ids,
-                               scaling=proto.scaling)
-                   if lora_l is not None else None)
-            xin = layers.rms_norm(x, p_l["ln1"], cfg.norm_eps)
-            qh, kh, vh = layers._qkv(p_l["attn"], xin, cfg, ctx)
-            with jax.named_scope("attention"):
-                qh = layers.apply_rope(qh, cos, sin)
-                kh = layers.apply_rope(kh, cos, sin)
-                ck = jax.lax.dynamic_update_slice(
-                    ck, kh.astype(ck.dtype)[None], (li, 0, idx, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cv, vh.astype(cv.dtype)[None], (li, 0, idx, 0, 0))
-                # the kernel reads layer li's first `bucket` tokens (and
-                # its o-bank rows) in place: the donated cache is never
-                # sliced or copied
-                at = dict(layer=li, window=bucket)
-                if o_bank is None:
-                    attn = kops.decode_attention(qh[:, 0], ck, cv, kv_len,
-                                                 **at)
-                    delta = None
+        routed = []
+        for name, first, count in self._stacks():
+            for j in range(count):
+                li = first + j
+                p_l = jax.tree.map(lambda a: a[j], params[name])
+                lora_l = {t: jax.tree.map(lambda a: a[li], tp)
+                          for t, tp in qkv_banks.items()} or None
+                ctx = (LoRAContext(mode=proto.mode, params=lora_l, ids=ids,
+                                   scaling=proto.scaling)
+                       if lora_l is not None else None)
+                xin = layers.rms_norm(x, p_l["ln1"], cfg.norm_eps)
+                qh, kh, vh = layers._qkv(p_l["attn"], xin, cfg, ctx)
+                with jax.named_scope("attention"):
+                    qh = layers.apply_rope(qh, cos, sin)
+                    kh = layers.apply_rope(kh, cos, sin)
+                    ck = jax.lax.dynamic_update_slice(
+                        ck, kh.astype(ck.dtype)[None], (li, 0, idx, 0, 0))
+                    cv = jax.lax.dynamic_update_slice(
+                        cv, vh.astype(cv.dtype)[None], (li, 0, idx, 0, 0))
+                    # the kernel reads layer li's first `bucket` tokens (and
+                    # its o-bank rows) in place: the donated cache is never
+                    # sliced or copied
+                    at = dict(layer=li, window=bucket)
+                    if o_bank is None:
+                        attn = kops.decode_attention(qh[:, 0], ck, cv, kv_len,
+                                                     **at)
+                        delta = None
+                    else:
+                        attn, delta = fused(qh[:, 0], ck, cv, kv_len, ids,
+                                            *o_args, **o_kw, **at)
+                    y = jnp.einsum("bhk,hkd->bd", attn, p_l["attn"]["wo"])
+                    if delta is not None:
+                        y = y + (proto.scaling * delta).astype(y.dtype)
+                    x = x + y[:, None]
+                if "moe" in p_l:
+                    with jax.named_scope("moe"):
+                        y, n_routed = moe.moe_decode(
+                            p_l["moe"],
+                            layers.rms_norm(x, p_l["ln2"], cfg.norm_eps), cfg)
+                        x = x + y
+                    routed.append(n_routed)
                 else:
-                    attn, delta = fused(qh[:, 0], ck, cv, kv_len, ids,
-                                        *o_args, **o_kw, **at)
-                y = jnp.einsum("bhk,hkd->bd", attn, p_l["attn"]["wo"])
-                if delta is not None:
-                    y = y + (proto.scaling * delta).astype(y.dtype)
-                x = x + y[:, None]
-            with jax.named_scope("mlp"):
-                x = x + layers.mlp_fwd(
-                    p_l["mlp"], layers.rms_norm(x, p_l["ln2"], cfg.norm_eps))
+                    with jax.named_scope("mlp"):
+                        x = x + layers.mlp_fwd(
+                            p_l["mlp"],
+                            layers.rms_norm(x, p_l["ln2"], cfg.norm_eps))
         with jax.named_scope("logits"):
             logits = layers.logits_fwd(params["embed"], x, cfg)
         new_cache = dict(cache)
         new_cache.update(k=ck, v=cv, index=idx + S)
+        if routed:
+            return logits, new_cache, jnp.stack(routed)
         return logits, new_cache
 
     def _o_bank_args(self, o_bank):
@@ -304,12 +339,14 @@ class RealModelExecutor:
         # max occupied length (padding slots attend junk but are ignored)
         with span("executor.decode.launch"):
             if unfused:
-                logits, self.cache = self._decode(self.params, self.bundles,
-                                                  tokens, self.cache, ids)
+                res = self._decode(self.params, self.bundles, tokens,
+                                   self.cache, ids)
             else:
-                logits, self.cache = self._decode(self.params, self.bundles,
-                                                  tokens, self.cache, ids,
-                                                  bucket=bucket)
+                res = self._decode(self.params, self.bundles, tokens,
+                                   self.cache, ids, bucket=bucket)
+            # an MoE model's fused step also counts its routed tokens
+            logits, self.cache, *routed = res
+            routed = routed[0] if routed else None
         self._host_len += 1
         out = {}
         # the unembedding is padded past the vocabulary; those columns are
@@ -320,7 +357,16 @@ class RealModelExecutor:
         with span("executor.decode.wait"):
             nxt.block_until_ready()
         with span("executor.decode.fetch"):
-            nxt = np.asarray(nxt)
+            if routed is None:
+                nxt = np.asarray(nxt)
+            else:
+                nxt, routed = jax.device_get((nxt, routed))
+        if routed is not None:
+            # summed over the expert layers: pairs routed to the experts
+            # held here, and held experts that had at least one
+            telemetry.event("executor.decode.experts",
+                            routed=int(routed.sum()),
+                            used=int((routed > 0).sum()))
         with span("executor.decode.emit"):
             for slot, rid in enumerate(self.slot_req):
                 if rid is not None:
@@ -454,6 +500,28 @@ def _dequantize_target(tp: Dict) -> Dict:
 def _dequantize_bundles(bundles: Dict) -> Dict:
     return {"layers": {t: _dequantize_target(tp)
                        for t, tp in bundles["layers"].items()}}
+
+
+def _one_stack(bundles: Dict) -> Dict:
+    """An MoE model's adapter stacks (`transformer.lora_defs_tree`: the
+    leading dense layers', then the expert layers') joined into one
+    ``layers`` stack by cache layer; one stack is returned as it is."""
+    if "dense_layers" not in bundles:
+        return bundles
+    return {"layers": jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                                   bundles["dense_layers"],
+                                   bundles["layers"])}
+
+
+def _split_stack(bundles: Dict, first_k: int) -> Dict:
+    """:func:`_one_stack` undone for `transformer.prefill`: the first
+    ``first_k`` layers' adapters in ``dense_layers``, the rest in
+    ``layers``."""
+    if not first_k:
+        return bundles
+    return {"dense_layers": jax.tree.map(lambda a: a[:first_k],
+                                         bundles["layers"]),
+            "layers": jax.tree.map(lambda a: a[first_k:], bundles["layers"])}
 
 
 def derive_cost_constants(samples) -> Dict[str, float]:

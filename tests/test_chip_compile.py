@@ -245,3 +245,68 @@ def test_fused_step_reads_the_cache_in_place(one_chip, monkeypatch):
             assert n < limit, line[:240]
     kernels = re.findall(r"%fused_decode_jd(?:\.\d+)? = .*custom-call", txt)
     assert len(kernels) == nl
+
+
+def test_moe_step_and_prefill_compile_for_v5e(one_chip, monkeypatch):
+    """deepseek-moe-16b's share of an 8-way expert split (8 of 64 experts
+    held, 2 shared, MHA 16/16) at published widths and three layers, the
+    leading dense one and two expert layers, compiled for a v5e: the fused
+    jd step has one kernel per layer and holds no copy of an expert's
+    weights (the layers read the stacked experts in place); the batch-1
+    prefill groups its routed pairs into the chip's ragged matmul."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import transformer as tf
+    from repro.models.param import init_params
+    from repro.serving import real_executor
+
+    full = get_config("deepseek-moe-16b")
+    cfg = dataclasses.replace(full, num_layers=3, moe=dataclasses.replace(
+        full.moe, held_experts=8))
+    b, s_max, bucket = 32, 384, 256
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def on_chip(x):
+        return shape(x.shape, x.dtype)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init_params(tf.model_defs(cfg), jax.random.PRNGKey(0),
+                            dtype_override=BF16)))
+    d, hq = cfg.d_model, cfg.num_heads * HD
+    bundles = {stack: {
+        t: {"U": shape((nl, 1, do, R), BF16), "V": shape((nl, 1, di, R), BF16),
+            "sigma": shape((nl, SN, R, R), BF16),
+            "cluster_of": shape((nl, SN), I32)}
+        for t, (di, do) in {"q": (d, hq), "k": (d, hq), "v": (d, hq),
+                            "o": (hq, d)}.items()}
+        for stack, nl in (("layers", 2), ("dense_layers", 1))}
+    # the one (L, …) stack the fused path serves from
+    bundles = jax.tree.map(on_chip, jax.eval_shape(real_executor._one_stack,
+                                                   bundles))
+    ex = real_executor.RealModelExecutor(cfg, params, bundles, "jd", b, s_max,
+                                         decode_path="fused")
+    cache = jax.tree.map(on_chip, ex.cache)
+    c1 = jax.tree.map(on_chip, tf.init_cache(cfg, 1, s_max, abstract=True))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    step = ex._decode.lower(params, bundles, shape((b, 1), I32), cache,
+                            shape((b,), I32), bucket=bucket).compile()
+    prefill = ex._prefill.lower(params, bundles, shape((1, 128), I32), c1,
+                                shape((1,), I32)).compile()
+    jax.clear_caches()
+
+    txt = step.as_text()
+    entry = txt.split("\nENTRY", 1)[1].split("\n}\n", 1)[0]
+    f = cfg.moe.d_ff_expert
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(", line)
+        if m and m.group(2) in ("slice", "copy", "copy-start", "dynamic-slice"):
+            for dims in re.findall(r"bf16\[([\d,]*)\]", m.group(1)):
+                assert str(f) not in dims.split(","), line[:240]
+    kernels = re.findall(r"%fused_decode_jd(?:\.\d+)? = .*custom-call", txt)
+    assert len(kernels) == cfg.num_layers
+    assert "ragged-dot" in prefill.as_text()
