@@ -98,8 +98,8 @@ def fused_vs_unfused_error(ex, reqs) -> dict:
     ref = last(unfused(ex.params, ex.bundles, tokens, ex.cache, ids)[0])
     shifted = last(unfused(ex.params, ex.bundles, tokens, ex.cache,
                            jnp.roll(ids, 1))[0])
-    got, ex.cache = ex._decode(ex.params, ex.bundles, tokens, ex.cache, ids,
-                               bucket=ex._bucket())
+    got, ex.cache, _ = ex._decode(ex.params, ex.bundles, tokens, ex.cache,
+                                  ids, bucket=ex._bucket())
     got = last(got)
     if not (np.isfinite(ref).all() and np.isfinite(got).all()):
         fail("non-finite logits")

@@ -7,6 +7,12 @@ requests prefill into a free slot (batch-1 prefill, cache splice); each
 engine decode step advances every occupied slot by one token with per-slot
 adapter ids (mode "lora": stacked A/B banks; mode "jd": U/V/Sigma bundles).
 
+Decode runs one step ahead of the host: the jitted step samples its greedy
+next ids itself, the next step takes them as its tokens without leaving the
+device, and each :meth:`RealModelExecutor.decode_step_real` launches step
+n+1 before it reads step n's ids (unless a slot's last token is step n's).
+A step launched ahead is dropped unread when occupancy changes under it.
+
 Decode paths (``decode_path``, surfaced as `EngineConfig.decode_path`):
 
 * ``"unfused"`` (default) — the generic `transformer.decode_step`
@@ -30,7 +36,7 @@ simulator's cost-model constants from the fused measurements
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,12 +85,25 @@ class RealModelExecutor:
         self.slot_adapter = np.zeros(max_batch, np.int32)
         self.slot_tokens = np.zeros(max_batch, np.int32)
         self.slot_len = np.zeros(max_batch, np.int32)
+        # decode steps each slot's request still needs: no step is launched
+        # ahead past a slot's last token
+        self.slot_left = np.zeros(max_batch, np.int32)
         # tokens each request's decode steps emitted: one per engine step,
         # so a finished request holds exactly max_new_tokens
         self.outputs: Dict[int, List[int]] = {}
-        # host mirror of the cache's scalar index: lets the fused paths pick
-        # a static KV bucket without a device sync
+        # host mirror of the cache's scalar index, the step launched ahead
+        # included: lets the fused paths pick a static KV bucket without a
+        # device sync
         self._host_len = 0
+        # the next step's inputs on the device: tokens (the ids the last
+        # launched step sampled) and the slots' adapter ids; None after an
+        # occupancy change, until the next step uploads the host copies
+        self._feed: Optional[Tuple[Array, Array]] = None
+        # the step launched ahead of the host's read of the step before it,
+        # as (bucket, its host-bound outputs); steps dropped unread since
+        # the last decode call
+        self._ahead: Optional[Tuple[int, Dict[str, Array]]] = None
+        self._discarded = 0
         if decode_path == "unfused":
             self.bundles = bundles
             self._decode = jax.jit(self._decode_fn)
@@ -128,8 +147,12 @@ class RealModelExecutor:
 
     def _decode_fn(self, params, bundles, tokens, cache, ids):
         proto = self._ctx(ids)
-        return tf.decode_step(params, tokens, self.cfg, cache,
-                              lora_params=bundles, lora_ctx_proto=proto)
+        logits, cache = tf.decode_step(params, tokens, self.cfg, cache,
+                                       lora_params=bundles,
+                                       lora_ctx_proto=proto)
+        with jax.named_scope("logits"):
+            out = {"ids": _greedy(logits, self.cfg.vocab_size)}
+        return logits, cache, out
 
     def _prefill_fn(self, params, bundles, tokens, cache, ids):
         if self.decode_path == "fused_q8":
@@ -171,9 +194,11 @@ class RealModelExecutor:
         semantics (scalar cache index, decode at max occupied length);
         ``bucket`` (static) truncates attention to the occupied KV prefix
         — masked tail blocks contribute exactly zero, so logits are
-        unchanged.  An MoE model's expert layers run every held expert on
-        every token (`moe.moe_decode`), and the step also returns the
-        tokens routed to each held expert, (expert layers, held) int32."""
+        unchanged.  Returns the logits, the cache and the step's host-bound
+        outputs: the greedy next ids, (B, 1) int32, the next step's tokens;
+        an MoE model's expert layers run every held expert on every token
+        (`moe.moe_decode`), and its step also returns the tokens routed to
+        each held expert, (expert layers, held) int32, as ``routed``."""
         cfg = self.cfg
         quant = self.decode_path == "fused_q8"
         banks = bundles["layers"]
@@ -245,11 +270,12 @@ class RealModelExecutor:
                             layers.rms_norm(x, p_l["ln2"], cfg.norm_eps))
         with jax.named_scope("logits"):
             logits = layers.logits_fwd(params["embed"], x, cfg)
+            out = {"ids": _greedy(logits, cfg.vocab_size)}
+        if routed:
+            out["routed"] = jnp.stack(routed)
         new_cache = dict(cache)
         new_cache.update(k=ck, v=cv, index=idx + S)
-        if routed:
-            return logits, new_cache, jnp.stack(routed)
-        return logits, new_cache
+        return logits, new_cache, out
 
     def _o_bank_args(self, o_bank):
         """The o-target's layer-stacked banks as the fused kernel takes
@@ -279,6 +305,7 @@ class RealModelExecutor:
 
     def prefill_request(self, req: Request, prompt: np.ndarray) -> None:
         slot = self.slot_req.index(None)
+        self._drop_ahead()
         with telemetry.span("executor.prefill", rid=req.rid,
                             prompt_len=int(req.prompt_len), slot=slot):
             self._prefill_into(slot, req, prompt)
@@ -318,66 +345,108 @@ class RealModelExecutor:
         with span("executor.prefill.fetch"):
             self.slot_tokens[slot] = int(first)
         self.slot_len[slot] = req.prompt_len
+        self.slot_left[slot] = req.max_new_tokens - req.generated
         self.outputs[req.rid] = []
 
     def decode_step_real(self) -> Dict[int, int]:
-        """One decode step for all occupied slots; returns {rid: token}."""
-        unfused = self.decode_path == "unfused"
+        """One decode step for all occupied slots; returns {rid: token}.
+
+        The step emitted here was launched by the call before when it ran
+        ahead (span attribute ``ahead``); ``discarded`` counts the steps
+        launched ahead and dropped since that call."""
+        ahead = self._ahead is not None
         # the attended KV window: each new one is a new fused-step program
-        bucket = self.s_max if unfused else self._bucket()
+        bucket = self._ahead[0] if ahead else self._window()
         with telemetry.span("executor.decode", slots=self.max_batch,
                             batch=self.max_batch - self.slot_req.count(None),
-                            bucket=bucket):
-            return self._decode_step(unfused, bucket)
+                            bucket=bucket, ahead=int(ahead),
+                            discarded=self._discarded):
+            self._discarded = 0
+            return self._decode_step()
 
-    def _decode_step(self, unfused: bool, bucket: int) -> Dict[int, int]:
+    def _decode_step(self) -> Dict[int, int]:
         span = telemetry.span
+        occupied = [s for s, rid in enumerate(self.slot_req)
+                    if rid is not None]
         with span("executor.decode.inputs"):
-            tokens = jnp.asarray(self.slot_tokens[:, None])
-            ids = jnp.asarray(self.slot_adapter)
-        # index must be per-slot; our cache uses a scalar index — decode at
-        # max occupied length (padding slots attend junk but are ignored)
+            if self._feed is None:
+                self._feed = (jnp.asarray(self.slot_tokens[:, None]),
+                              jnp.asarray(self.slot_adapter))
         with span("executor.decode.launch"):
-            if unfused:
-                res = self._decode(self.params, self.bundles, tokens,
-                                   self.cache, ids)
-            else:
-                res = self._decode(self.params, self.bundles, tokens,
-                                   self.cache, ids, bucket=bucket)
-            # an MoE model's fused step also counts its routed tokens
-            logits, self.cache, *routed = res
-            routed = routed[0] if routed else None
-        self._host_len += 1
-        out = {}
-        # the unembedding is padded past the vocabulary; those columns are
-        # not tokens
+            _, step = self._ahead or self._launch()
+            # the next step goes on the device behind this one before the
+            # host reads this one's ids, unless a slot ends with this one
+            self._ahead = (self._launch() if occupied and
+                           self.slot_left[occupied].min() > 1 else None)
         with span("executor.decode.sample"):
-            nxt = jnp.argmax(logits[:, -1, :self.cfg.vocab_size], axis=-1)
+            self.slot_left[occupied] -= 1
+            self.slot_len[occupied] += 1
         # the host read in two calls: waiting for the device, then the copy
         with span("executor.decode.wait"):
-            nxt.block_until_ready()
+            step["ids"].block_until_ready()
         with span("executor.decode.fetch"):
-            if routed is None:
-                nxt = np.asarray(nxt)
-            else:
-                nxt, routed = jax.device_get((nxt, routed))
-        if routed is not None:
+            step = jax.device_get(step)
+        if "routed" in step:
             # summed over the expert layers: pairs routed to the experts
             # held here, and held experts that had at least one
             telemetry.event("executor.decode.experts",
-                            routed=int(routed.sum()),
-                            used=int((routed > 0).sum()))
+                            routed=int(step["routed"].sum()),
+                            used=int((step["routed"] > 0).sum()))
+        out = {}
         with span("executor.decode.emit"):
-            for slot, rid in enumerate(self.slot_req):
-                if rid is not None:
-                    self.slot_tokens[slot] = nxt[slot]
-                    self.slot_len[slot] += 1
-                    out[rid] = int(nxt[slot])
-                    self.outputs.setdefault(rid, []).append(int(nxt[slot]))
+            nxt = step["ids"][:, 0]
+            for slot in occupied:
+                tok = int(nxt[slot])
+                self.slot_tokens[slot] = tok
+                out[self.slot_req[slot]] = tok
+                self.outputs.setdefault(self.slot_req[slot], []).append(tok)
         return out
+
+    def _window(self) -> int:
+        return self.s_max if self.decode_path == "unfused" else self._bucket()
+
+    def _launch(self) -> Tuple[int, Dict[str, Array]]:
+        """Put the next decode step on the device, fed from :attr:`_feed`;
+        returns its window and its host-bound outputs.  Every slot decodes
+        at the cache's one scalar index, the max occupied length (padding
+        slots attend junk and are ignored)."""
+        tokens, ids = self._feed
+        bucket = self._window()
+        if self.decode_path == "unfused":
+            res = self._decode(self.params, self.bundles, tokens, self.cache,
+                               ids)
+        else:
+            res = self._decode(self.params, self.bundles, tokens, self.cache,
+                               ids, bucket=bucket)
+        logits, self.cache, *out = res
+        # a step replaced by one that returns only logits and cache (as
+        # `bench.run.run_cell`'s ``plant`` may replace it) is sampled here
+        out = out[0] if out else {"ids": _greedy(logits, self.cfg.vocab_size)}
+        self._host_len += 1
+        # queue the copy to the host right behind this step, before any
+        # step launched after it
+        for x in out.values():
+            x.copy_to_host_async()
+        self._feed = (out["ids"], ids)
+        return bucket, out
+
+    def _drop_ahead(self) -> None:
+        """Occupancy is about to change (or a slot's state to be read): the
+        device feed goes stale, and a step launched ahead is dropped unread.
+        Its K/V write at the host index is rewritten by the next step from
+        the same inputs for every slot that continues, and by the splice
+        for a new slot, so the drop is exact."""
+        self._feed = None
+        if self._ahead is None:
+            return
+        self._ahead = None
+        self._discarded += 1
+        self._host_len -= 1
+        self.cache["index"] = jnp.asarray(self._host_len, jnp.int32)
 
     def release(self, rid: int) -> None:
         slot = self.slot_req.index(rid)
+        self._drop_ahead()
         self.slot_req[slot] = None
         if all(r is None for r in self.slot_req):
             # drained: the next wave prefills from position 0 again rather
@@ -393,6 +462,7 @@ class RealModelExecutor:
         the engine frees it via :meth:`release` once the checkpoint is on
         the wire (invariant M3)."""
         slot = self.slot_req.index(rid)
+        self._drop_ahead()
 
         def take(x):
             if x.ndim == 0:
@@ -419,6 +489,7 @@ class RealModelExecutor:
         but attend padding for the shallower slot, like any mixed-depth
         batch under the scalar-index cache model."""
         slot = self.slot_req.index(None)
+        self._drop_ahead()
 
         def splice(dst, src):
             if dst.ndim == 0:
@@ -436,6 +507,7 @@ class RealModelExecutor:
         self.slot_adapter[slot] = state["adapter"]
         self.slot_tokens[slot] = state["token"]
         self.slot_len[slot] = state["len"]
+        self.slot_left[slot] = req.max_new_tokens - req.generated
 
     # cost hooks (engine uses wall-clock when run_real is used instead)
     def decode_step_time(self, batch) -> float:
@@ -455,6 +527,14 @@ class RealModelExecutor:
         t0 = time.perf_counter()
         self.prefill_request(req, self.prompt_for(req))
         return time.perf_counter() - t0
+
+
+def _greedy(logits, vocab: int):
+    """The greedy next ids of a decode step's logits, (B, 1) int32: the
+    unembedding is padded past the vocabulary, and those columns are not
+    tokens."""
+    nxt = jnp.argmax(logits[:, -1, :vocab], axis=-1)
+    return nxt.astype(jnp.int32)[:, None]
 
 
 def _quantize_bundles(bundles: Dict, mode: str) -> Dict:

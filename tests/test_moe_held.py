@@ -162,10 +162,11 @@ def test_fused_moe_step_matches_decode_step(mode):
     unfused, fused = _served(mode, "unfused"), _served(mode, "fused")
     tokens = jnp.asarray(unfused.slot_tokens[:, None])
     ids = jnp.asarray(unfused.slot_adapter)
-    want, _ = unfused._decode(unfused.params, unfused.bundles, tokens,
-                              unfused.cache, ids)
-    got, _, routed = fused._decode(fused.params, fused.bundles, tokens,
-                                   fused.cache, ids, bucket=fused._bucket())
+    want, _, _ = unfused._decode(unfused.params, unfused.bundles, tokens,
+                                 unfused.cache, ids)
+    got, _, out = fused._decode(fused.params, fused.bundles, tokens,
+                                fused.cache, ids, bucket=fused._bucket())
+    routed = out["routed"]
     V = fused.cfg.vocab_size
     # float32 weights; the K/V cache is bf16 on both paths, so what is left
     # is the order of float32 sums (attention kernel, expert combine)
@@ -182,9 +183,11 @@ def test_expert_counters_equal_a_recount_of_the_routing(monkeypatch):
     """Each decode step's ``executor.decode.experts`` event holds the
     (token, expert) pairs routed to the held experts and the held experts
     used, summed over the expert layers: recounted here in numpy from the
-    routing of `transformer.decode_step` on the same cache."""
+    routing of `transformer.decode_step` on the same cache (the test's
+    own: the executors' caches hold the step launched ahead)."""
     cfg = dc.replace(_cfg(**SERVED), scan_layers=False)
     fused, unfused = _served("jd", "fused", cfg), _served("jd", "unfused", cfg)
+    cache = unfused.cache
     choices = []
     route = moe._route
 
@@ -200,9 +203,9 @@ def test_expert_counters_equal_a_recount_of_the_routing(monkeypatch):
         tokens = jnp.asarray(unfused.slot_tokens[:, None])
         ids = jnp.asarray(unfused.slot_adapter)
         # eager (no jit, no scan): each expert layer's choices are arrays
-        tf.decode_step(unfused.params, tokens, cfg, unfused.cache,
-                       lora_params=unfused.bundles,
-                       lora_ctx_proto=unfused._ctx(ids))
+        _, cache = tf.decode_step(unfused.params, tokens, cfg, cache,
+                                  lora_params=unfused.bundles,
+                                  lora_ctx_proto=unfused._ctx(ids))
         local = np.stack(choices) - SERVED["offset"]        # (layers, B, k)
         per = np.stack([(local == e).sum((1, 2))
                         for e in range(SERVED["held"])], -1)
@@ -230,13 +233,15 @@ def test_dense_models_step_reports_no_experts():
 
 
 # the dense fused step's jaxpr, with each equation's named scopes, as the
-# program had it before the MoE family joined the fused path: a qwen3 smoke
-# model of 2 layers, 4 slots, a 128-token window, the kernels in Pallas'
-# interpreter (so the kernels' bodies and index maps are in it too).  A
-# change of JAX's version changes the text; regenerate them then.
+# program had it before the MoE family joined the fused path, and since with
+# its greedy sampling (argmax over the vocabulary, in scope `logits`, the ids
+# a fourth output): a qwen3 smoke model of 2 layers, 4 slots, a 128-token
+# window, the kernels in Pallas' interpreter (so the kernels' bodies and
+# index maps are in it too).  A change of JAX's version changes the text;
+# regenerate them then.
 DENSE_STEP = {
-    "jd": "41b467c428c9e1a5d8afa07678844a18e67143d5f368542cc71bfbe2ee56ba7a",
-    "lora": "e225c85933edc2088e33dbe2978ec821c0651391833aea631f22dce38aef3d66",
+    "jd": "08b40784a05e9c80830dcb9404384452bea110ddc7dfc997eb4cc5ea5a906fb6",
+    "lora": "84ff129ffaaf1a0a5da7918ff3fb397ee0f6e51d491a16f3a01f454b5abe2e84",
 }
 
 
